@@ -59,8 +59,8 @@ def main():
 
     n = args.cells
     f, jac, y0 = batched_robertson(n)
-    policy = (ExecPolicy(backend="pallas", interpret=True,
-                         batch_tile=args.batch_tile) if args.pallas
+    policy = (ExecPolicy(backend="pallas", batch_tile=args.batch_tile)
+              if args.pallas
               else XLA_FUSED)
     ctx = Context(policy=policy)
     opts = ctx.options(rtol=1e-5, atol=1e-10, max_steps=100_000)

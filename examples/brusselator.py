@@ -39,10 +39,11 @@ def main():
     ap.add_argument("--solver", default="both",
                     choices=["task-local", "global", "both"])
     ap.add_argument("--pallas", action="store_true",
-                    help="use the Pallas block-solve kernel (interpret mode)")
+                    help="use the Pallas block-solve kernel (compiled on a "
+                         "TPU, interpret mode elsewhere)")
     args = ap.parse_args()
 
-    policy = (ExecPolicy(backend="pallas", interpret=True) if args.pallas
+    policy = (ExecPolicy(backend="pallas") if args.pallas
               else XLA_FUSED)
     print(f"brusselator1d: nx={args.nx} (={3*args.nx} ODEs), tf={args.tf}, "
           f"eps=5e-6 (stiff)")

@@ -70,7 +70,7 @@ def main():
           f"tf={args.tf}")
 
     prob = IVP(f=f, jac=jac, jac_sparsity=pattern, y0=y0)
-    policy = (ExecPolicy(backend="pallas", interpret=True) if args.pallas
+    policy = (ExecPolicy(backend="pallas") if args.pallas
               else XLA_FUSED)
     ctx = Context(policy=policy)
     opts = ctx.options(rtol=args.rtol, atol=1e-9, max_steps=400_000)

@@ -49,6 +49,9 @@ REPO_ROOT = Path(__file__).resolve().parents[3]
 OPAQUE_PRIMS = frozenset({"pallas_call", "custom_jvp_call",
                           "custom_vjp_call", "custom_lin"})
 
+#: the primitive a ``jax.jit`` call site traces to
+JIT_PRIM = "jit"
+
 
 # ---------------------------------------------------------------------------
 # Violations and the rule registry
@@ -109,7 +112,7 @@ def load_rules():
 def subjaxprs(eqn):
     """Yield every sub-jaxpr stored in an equation's params (scan's
     ``jaxpr``, while's ``cond_jaxpr``/``body_jaxpr``, cond's
-    ``branches`` list, pjit's ``jaxpr``, ...)."""
+    ``branches`` list, jit's ``jaxpr``, ...)."""
     from jax.extend import core as jex_core
     for val in eqn.params.values():
         vals = val if isinstance(val, (list, tuple)) else (val,)
@@ -123,11 +126,11 @@ def subjaxprs(eqn):
 def is_opaque(eqn, opaque_names=frozenset()) -> bool:
     """True when the equation is a kernel boundary the walkers must not
     descend into: a Pallas call, a custom-derivative wrapper, or a
-    ``pjit`` of one of the named (jitted) kernel entry points."""
+    ``jit`` of one of the named (jitted) kernel entry points."""
     name = eqn.primitive.name
     if name in OPAQUE_PRIMS:
         return True
-    return name == "pjit" and eqn.params.get("name") in opaque_names
+    return name == JIT_PRIM and eqn.params.get("name") in opaque_names
 
 
 def iter_eqns(jaxpr, opaque_names=frozenset()):
@@ -151,7 +154,7 @@ def innermost_while_bodies(jaxpr, opaque_names=frozenset()):
     while/scan at any non-opaque depth — for the ensemble integrators
     these are exactly the Newton iteration loops (the adaptive step
     loop encloses them; the kernels' internal scans sit behind opaque
-    pjit boundaries on the pallas backend)."""
+    jit boundaries on the pallas backend)."""
     out = []
     for eqn in iter_eqns(jaxpr, opaque_names):
         if eqn.primitive.name != "while":
@@ -177,7 +180,7 @@ def eqn_src(eqn) -> Optional[Tuple[str, int]]:
 
 def kernel_wrapper_names() -> frozenset:
     """Names of the jitted Pallas kernel entry points in
-    :mod:`repro.kernels.ops` — their ``pjit`` equations carry the
+    :mod:`repro.kernels.ops` — their ``jit`` equations carry the
     function name, which is how the walkers treat kernel internals as
     opaque."""
     from repro.kernels import ops as kops
@@ -208,7 +211,7 @@ class TraceTarget:
 
 def _hot_policy():
     # the pallas(interpret) path: kernel internals sit behind opaque
-    # pjit boundaries, so the trace shows exactly the *integrator's*
+    # jit boundaries, so the trace shows exactly the *integrator's*
     # layout behavior — what the PR 5 no-transpose guarantee is about.
     # (The jnp oracles inline einsum/transpose into the body by design.)
     from repro.core.policies import ExecPolicy
@@ -546,7 +549,7 @@ class LintContext:
 
     @property
     def donation_targets(self) -> List[TraceTarget]:
-        # the hot-loop traces contain the _donated_loop pjit; sharing
+        # the hot-loop traces contain the _donated_loop jit; sharing
         # the TraceTarget objects shares the cached trace.
         if self._donation_targets is None:
             self._donation_targets = self.hot_loop_targets

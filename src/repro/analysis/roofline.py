@@ -93,6 +93,24 @@ DEVICES: Dict[str, Device] = {
 }
 
 
+#: ``jax.Device.device_kind`` -> roofline device name.  A compiled
+#: policy on a chip missing here fails instead of borrowing another
+#: chip's row.
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v4": "tpu_v4",
+}
+
+
+def device_for_kind(kind: str) -> str:
+    """The roofline device name of a ``jax.Device.device_kind``."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(f"no roofline device for device_kind {kind!r}; "
+                         f"known kinds: {sorted(DEVICE_KINDS)}") from None
+
+
 def get_device(name: str) -> Device:
     try:
         return DEVICES[name]

@@ -7,7 +7,7 @@ aliases another argument of the same call — two tree leaves bound to
 one buffer would make XLA write through a live alias — and (b) nothing
 reads a donated buffer after the call, since donation invalidates it.
 Both properties are visible in the trace: this rule scans every
-``pjit`` equation with ``donated_invars`` set, flags repeated
+``jit`` equation with ``donated_invars`` set, flags repeated
 variables among its donated inputs, and flags any later equation (or
 an enclosing output) that mentions a donated variable again.
 """
@@ -17,7 +17,7 @@ from repro.analysis import lint
 def _scan(where, jaxpr, opaque_names, out):
     from jax.extend import core as jex_core
     for idx, eqn in enumerate(jaxpr.eqns):
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == lint.JIT_PRIM:
             don = eqn.params.get("donated_invars", ())
             if any(don):
                 invars = [v if isinstance(v, jex_core.Var) else None

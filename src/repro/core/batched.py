@@ -720,18 +720,21 @@ def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
         Z = dv.history_rescale_soa(jnp.transpose(W, (1, 2, 0)), c.Z,
                                    active & (eta_clip != one), policy)
         qi = c.q - 1
-        alphas = _cv._ALPHA_T[qi].astype(dtype)      # (nsys, QMAX+1)
-        beta = _cv._BETA_T[qi].astype(dtype)         # (nsys,)
+        alphas = jnp.asarray(_cv._ALPHA_T, dtype)[qi]   # (nsys, QMAX+1)
+        beta = jnp.asarray(_cv._BETA_T, dtype)[qi]      # (nsys,)
         p_pred = jnp.minimum(nvalid, c.q)
-        pred_c = _cv._PREDP_T[p_pred].astype(dtype)
+        pred_c = jnp.asarray(_cv._PREDP_T, dtype)[p_pred]
         # predictor / psi: per-system coefficient contractions over the
         # history, evaluated as the AoS einsum on transposed views so
         # the jnp backend keeps the pre-SoA accumulation order bitwise
         # (XLA folds the layout changes into the contraction).  O(Q*n*
-        # nsys) once per step — NOT per Newton iteration.
+        # nsys) once per step — NOT per Newton iteration.  HIGHEST keeps
+        # a TPU from rounding the operands to bfloat16.
         Zaos = jnp.transpose(Z, (2, 0, 1))           # (nsys, QMAX+1, n)
-        y_pred = jnp.einsum("sj,sjk->sk", pred_c, Zaos).T    # (n, nsys)
-        psi = (-jnp.einsum("sj,sjk->sk", alphas[:, 1:], Zaos[:, :-1])).T
+        y_pred = jnp.einsum("sj,sjk->sk", pred_c, Zaos,
+                            precision=lax.Precision.HIGHEST).T  # (n, nsys)
+        psi = (-jnp.einsum("sj,sjk->sk", alphas[:, 1:], Zaos[:, :-1],
+                           precision=lax.Precision.HIGHEST)).T
         gamma = beta * hs                            # (nsys,)
         t_new = c.t + hs
         w = 1.0 / (opts.rtol * jnp.abs(Z[0]) + opts.atol)   # (n, nsys)
@@ -1003,7 +1006,6 @@ def ensemble_bdf_integrate_sharded(f: Callable, jac: Callable,
     """
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_ensemble_mesh
-    from repro.parallel.sharding import shard_map_compat
 
     # an explicit None is the documented "no native SoA form" default of
     # the non-sharded API — only an actual callable is rejected here
@@ -1053,9 +1055,9 @@ def ensemble_bdf_integrate_sharded(f: Callable, jac: Callable,
 
     stats_spec = EnsembleStats(*([spec] * len(EnsembleStats._fields)))
     params_spec = jax.tree_util.tree_map(lambda _: spec, params)
-    fn = shard_map_compat(body, mesh,
-                          in_specs=(spec, spec, spec, params_spec),
-                          out_specs=(spec, stats_spec))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(spec, spec, spec, params_spec),
+                       out_specs=(spec, stats_spec), check_vma=False)
     y, st = fn(y0, t0a, tfa, params)
     if st.nli is not None:
         # each shard broadcast its own local Krylov total over its slice;

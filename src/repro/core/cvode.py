@@ -21,6 +21,7 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.flatten_util import ravel_pytree
 
@@ -56,9 +57,12 @@ for p in range(1, QMAX + 1):
     row = [((-1.0) ** j) * math.comb(p + 1, j + 1) for j in range(p + 1)]
     _PREDP.append(row + [0.0] * (QMAX + 1 - len(row)))
 
-_ALPHA_T = jnp.array(_BDF_ALPHA)
-_BETA_T = jnp.array(_BDF_BETA)
-_PREDP_T = jnp.array(_PREDP)
+# host float64 tables: exact whether or not x64 is on when this module
+# is imported, and importing it touches no device.  Index them through
+# jnp.asarray(table, dtype)[q] at the working precision.
+_ALPHA_T = np.array(_BDF_ALPHA)
+_BETA_T = np.array(_BDF_BETA)
+_PREDP_T = np.array(_PREDP)
 
 
 def _lagrange_matrix(eta, q_cur):
@@ -157,10 +161,10 @@ def bdf_integrate(f: Callable, y0, t0, tf, *, order: int = 5,
         Z = jnp.einsum("ji,ik->jk", _lagrange_matrix(eta_clip, nvalid_m1),
                        c.Z)
         qi = c.q - 1
-        alphas = _ALPHA_T[qi]                       # (QMAX+1,)
-        beta = _BETA_T[qi]
+        alphas = jnp.asarray(_ALPHA_T)[qi]          # (QMAX+1,)
+        beta = jnp.asarray(_BETA_T)[qi]
         p_pred = jnp.minimum(nvalid_m1, c.q)        # predictor degree
-        pred_c = _PREDP_T[p_pred]
+        pred_c = jnp.asarray(_PREDP_T)[p_pred]
         y_pred = pred_c @ Z                          # (n,)
         psi = -(alphas[1:] @ Z[:-1])                 # uses y_n .. y_{n-q+1}
         # NOTE: alphas[j] multiplies y_{n+1-j}; history Z[i] = y_{n-i}
@@ -316,8 +320,8 @@ def bdf_fixed(f: Callable, y0, t0, tf, n_steps: int, *, order: int = 2,
     n = y0_flat.shape[0]
     h = (tf - t0) / n_steps
     qi = order - 1
-    alphas = _ALPHA_T[qi]
-    beta = _BETA_T[qi]
+    alphas = jnp.asarray(_ALPHA_T)[qi]
+    beta = jnp.asarray(_BETA_T)[qi]
 
     def f_flat(t, yf):
         return ravel_pytree(f(t, unravel(yf)))[0]

@@ -65,7 +65,7 @@ def block_solve(A: BlockDiagMatrix, b: jnp.ndarray,
     if policy.backend == "pallas":
         from repro.kernels import ops as kops
         xb = kops.block_solve(data, bb, batch_tile=policy.batch_tile,
-                              interpret=policy.interpret)
+                              interpret=policy.interpreted())
     else:
         xb = gauss_jordan_batched(data, bb)
     return xb.reshape(b.shape)

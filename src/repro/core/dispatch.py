@@ -99,7 +99,7 @@ def _pl_linear_combination(coeffs, vecs, *, policy: ExecPolicy) -> Pytree:
         n = X.shape[1]
         z = kops.linear_combination(
             _coeff_array(coeffs, want), X,
-            block_elems=_stream_tile(n, policy), interpret=policy.interpret)
+            block_elems=_stream_tile(n, policy), interpret=policy.interpreted())
         out.append(z)
     return _rebuild(vecs[0], out)
 
@@ -125,7 +125,7 @@ def _pl_scale_add_multi(coeffs, x, ys, *, policy: ExecPolicy):
         n = Y.shape[1]
         Z = kops.scale_add_multi(
             _coeff_array(coeffs, want), xl.ravel().astype(want), Y,
-            block_elems=_stream_tile(n, policy), interpret=policy.interpret)
+            block_elems=_stream_tile(n, policy), interpret=policy.interpreted())
         per_leaf.append(Z)
     return [_rebuild(x, [Z[k] for Z in per_leaf]) for k in range(K)]
 
@@ -139,7 +139,7 @@ def _pl_dot(x, y, *, policy: ExecPolicy):
         n = xl.size
         acc = acc + kops.dot(
             xl.ravel().astype(acc_t), yl.ravel().astype(acc_t),
-            reduce_tile=_reduce_tile(n, policy), interpret=policy.interpret)
+            reduce_tile=_reduce_tile(n, policy), interpret=policy.interpreted())
     return acc
 
 
@@ -153,7 +153,7 @@ def _pl_wrms_norm(x, w, *, policy: ExecPolicy):
         ss = ss + kops.wrms_ss(
             xl.ravel().astype(acc_t), wl.ravel().astype(acc_t),
             reduce_tile=_reduce_tile(xl.size, policy),
-            interpret=policy.interpret)
+            interpret=policy.interpreted())
     return jnp.sqrt(ss / n_total)
 
 
@@ -168,7 +168,7 @@ def _pl_wrms_norm_mask(x, w, mask, *, policy: ExecPolicy):
             xl.ravel().astype(acc_t), wl.ravel().astype(acc_t),
             ml.ravel().astype(acc_t),
             reduce_tile=_reduce_tile(xl.size, policy),
-            interpret=policy.interpret)
+            interpret=policy.interpreted())
     return jnp.sqrt(ss / n_total)
 
 
@@ -185,7 +185,7 @@ def _pl_dot_prod_multi(x, ys, *, policy: ExecPolicy):
         acc = acc + kops.dot_prod_multi(
             xl.ravel().astype(acc_t), Y,
             reduce_tile=_reduce_tile(xl.size, policy),
-            interpret=policy.interpret)
+            interpret=policy.interpreted())
     return acc
 
 
@@ -199,7 +199,7 @@ def _pl_wrms_ss(x, w, *, policy: ExecPolicy):
         ss = ss + kops.wrms_ss(
             xl.ravel().astype(acc_t), wl.ravel().astype(acc_t),
             reduce_tile=_reduce_tile(xl.size, policy),
-            interpret=policy.interpret)
+            interpret=policy.interpreted())
     return ss
 
 
@@ -222,10 +222,7 @@ def _gj_vmem(policy: ExecPolicy):
     roofline device entry (None -> the kernels' GJ_VMEM_BYTES default;
     only consulted in compiled mode)."""
     from repro.analysis.roofline import get_device
-    try:
-        return get_device(policy.device_name()).vmem_bytes
-    except ValueError:
-        return None
+    return get_device(policy.device_name()).vmem_bytes
 
 
 def _jnp_block_solve_soa(A, r, *, policy=None):
@@ -238,7 +235,7 @@ def _jnp_block_solve_soa(A, r, *, policy=None):
 def _pl_block_solve_soa(A, r, *, policy: ExecPolicy):
     from repro.kernels import ops as kops
     return kops.block_solve_soa(A, r, batch_tile=policy.batch_tile,
-                                interpret=policy.interpret,
+                                interpret=policy.interpreted(),
                                 vmem_bytes=_gj_vmem(policy))
 
 
@@ -250,7 +247,7 @@ def _jnp_block_inverse_soa(A, *, policy=None):
 def _pl_block_inverse_soa(A, *, policy: ExecPolicy):
     from repro.kernels import ops as kops
     return kops.block_inverse_soa(A, batch_tile=policy.batch_tile,
-                                  interpret=policy.interpret,
+                                  interpret=policy.interpreted(),
                                   vmem_bytes=_gj_vmem(policy))
 
 
@@ -262,7 +259,7 @@ def _jnp_blockdiag_spmv_soa(A, x, *, policy=None):
 def _pl_blockdiag_spmv_soa(A, x, *, policy: ExecPolicy):
     from repro.kernels import ops as kops
     return kops.blockdiag_spmv_soa(A, x, batch_tile=policy.batch_tile,
-                                   interpret=policy.interpret)
+                                   interpret=policy.interpreted())
 
 
 # ---------------------------------------------------------------------------
@@ -284,7 +281,7 @@ def _pl_newton_residual_soa(z, fval, psi, gamma, negate, *,
     from repro.kernels import ops as kops
     return kops.newton_residual_soa(z, fval, psi, gamma,
                                     batch_tile=policy.batch_tile,
-                                    interpret=policy.interpret,
+                                    interpret=policy.interpreted(),
                                     negate=negate)
 
 
@@ -297,7 +294,7 @@ def _pl_masked_update_wrms_soa(z, dz, w, mask, *, policy: ExecPolicy):
     from repro.kernels import ops as kops
     return kops.masked_update_wrms_soa(z, dz, w, mask,
                                        batch_tile=policy.batch_tile,
-                                       interpret=policy.interpret)
+                                       interpret=policy.interpreted())
 
 
 def _jnp_history_rescale_soa(W, Z, active, *, policy=None):
@@ -309,7 +306,7 @@ def _pl_history_rescale_soa(W, Z, active, *, policy: ExecPolicy):
     from repro.kernels import ops as kops
     return kops.history_rescale_soa(W, Z, active,
                                     batch_tile=policy.batch_tile,
-                                    interpret=policy.interpret)
+                                    interpret=policy.interpreted())
 
 
 def _jnp_wrms_soa(v, w, *, policy=None):
@@ -320,7 +317,7 @@ def _jnp_wrms_soa(v, w, *, policy=None):
 def _pl_wrms_soa(v, w, *, policy: ExecPolicy):
     from repro.kernels import ops as kops
     return kops.wrms_soa(v, w, batch_tile=policy.batch_tile,
-                         interpret=policy.interpret)
+                         interpret=policy.interpreted())
 
 
 # ---------------------------------------------------------------------------
@@ -344,7 +341,7 @@ def _pl_csr_spmv(data, x, pattern, *, policy: ExecPolicy):
     return kops.csr_spmv(data, x, indptr=tuple(indptr),
                          indices=tuple(indices),
                          block_elems=policy.block_elems,
-                         interpret=policy.interpret)
+                         interpret=policy.interpreted())
 
 
 def _jnp_bsr_spmv_soa(values, x, pattern, *, policy=None):
@@ -359,7 +356,7 @@ def _pl_bsr_spmv_soa(values, x, pattern, *, policy: ExecPolicy):
     return kops.bsr_spmv_soa(values, x, brows=tuple(brows),
                              bcols=tuple(bcols), nblk=nblk,
                              batch_tile=policy.batch_tile,
-                             interpret=policy.interpret)
+                             interpret=policy.interpreted())
 
 
 def _jnp_bsr_block_jacobi_inverse_soa(values, pattern, *, policy=None):
@@ -375,7 +372,7 @@ def _pl_bsr_block_jacobi_inverse_soa(values, pattern, *,
     return kops.bsr_diag_inverse_soa(values, brows=tuple(brows),
                                      bcols=tuple(bcols), nblk=nblk,
                                      batch_tile=policy.batch_tile,
-                                     interpret=policy.interpret)
+                                     interpret=policy.interpreted())
 
 
 def _ignore_policy(fn):

@@ -52,9 +52,10 @@ Newton solvers take a ``policy=`` kwarg; :class:`MeshVectorSpec` carries
 one per vector.  At the run level, a
 :class:`repro.core.context.Context` owns the policy and
 ``ctx.options(...)`` builds ODEOptions bound to it.  ``backend='jnp'`` (XLA_FUSED, the default) reproduces
-the pre-dispatch behavior exactly; ``backend='pallas'`` with
-``interpret=True`` runs the fused kernels CPU-emulated (CI parity
-checks), with ``interpret=False`` compiled to Mosaic on TPU.
+the pre-dispatch behavior exactly; ``backend='pallas'`` compiles the
+fused kernels to Mosaic when the program runs on a TPU and emulates
+them in interpret mode anywhere else (the CPU parity checks).
+``interpret=True``/``False`` forces either mode.
 
 Cost-model-driven dispatch (``backend='auto'``)
 -----------------------------------------------
@@ -74,7 +75,7 @@ is the ready-made auto policy.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Tuple
+from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
@@ -95,10 +96,14 @@ class ExecPolicy:
                     multiple divisor of the lane-padded batch not above
                     this, so any nsys — including non-multiples of 128 —
                     pads by less than one lane of identity blocks).
-    interpret     : run Pallas in interpret mode (CPU validation).
+    interpret     : run Pallas in interpret mode.  ``None`` (default)
+                    derives it from where the program runs: compiled on
+                    a TPU, interpreted on any other backend.
     device        : roofline device-table name used by 'auto' dispatch
                     ('' derives it: interpret -> 'interpret', compiled
-                    -> 'tpu_v5e').
+                    -> the default device's ``device_kind`` mapped
+                    through :data:`repro.analysis.roofline.DEVICE_KINDS`;
+                    an unmapped kind raises).
     op_overrides  : per-op backend pins for 'auto' — a hashable tuple of
                     ``(op_name, backend)`` pairs (the policy keys jit
                     caches, so this is not a dict).  Validated at
@@ -112,7 +117,7 @@ class ExecPolicy:
     block_elems: int = 8 * 128
     reduce_tile: int = 64 * 128
     batch_tile: int = 128
-    interpret: bool = True  # flipped to False on real TPU deployments
+    interpret: Optional[bool] = None
     device: str = ""
     op_overrides: Tuple[Tuple[str, str], ...] = ()
 
@@ -136,9 +141,21 @@ class ExecPolicy:
                 "ops: %s" % ("; ".join(bad),
                              ", ".join(sorted(valid_ops))))
 
+    def interpreted(self) -> bool:
+        """Whether Pallas kernels run in interpret mode under this
+        policy (``interpret``, or derived from the default backend)."""
+        from repro.kernels import resolve_interpret
+        return resolve_interpret(self.interpret)
+
     def device_name(self) -> str:
         """The roofline device this policy targets ('' -> derived)."""
-        return self.device or ("interpret" if self.interpret else "tpu_v5e")
+        if self.device:
+            return self.device
+        if self.interpreted():
+            return "interpret"
+        import jax
+        from repro.analysis.roofline import device_for_kind
+        return device_for_kind(jax.devices()[0].device_kind)
 
     def backend_for(self, op: str) -> str:
         """Backend for one op: an ``op_overrides`` pin wins, else the

@@ -12,6 +12,20 @@ import jax
 import jax.numpy as jnp
 
 
+def robertson_rates(nsys: int):
+    """Per-cell Robertson rate constants ``(k1, k2, k3)``, each
+    ``(nsys,)`` in the default float dtype, drawn from fixed PRNG keys:
+    k2 spans [0.5e4, 1.5e4) and k3 two decades around 3e7.  Every
+    Robertson ensemble below closes over these, so a reference run can
+    rebuild exactly the same cells from them."""
+    key = jax.random.PRNGKey(0)
+    k1 = 0.04 * jnp.ones((nsys,))
+    k2 = 1e4 * (0.5 + jax.random.uniform(key, (nsys,)))
+    k3 = 3e7 * 10.0 ** jax.random.uniform(jax.random.PRNGKey(1), (nsys,),
+                                          minval=-1.0, maxval=1.0)
+    return k1, k2, k3
+
+
 def batched_robertson(nsys: int):
     """Robertson kinetics with per-cell rate constants — ``nsys``
     independent 3-species systems whose stiffness varies cell to cell
@@ -22,11 +36,7 @@ def batched_robertson(nsys: int):
     ``jac(t, y) -> (nsys, 3, 3)`` are vectorized over the batch with the
     rates closed over; ``y0`` is the standard ``[1, 0, 0]`` start.
     """
-    key = jax.random.PRNGKey(0)
-    k1 = 0.04 * jnp.ones((nsys,))
-    k2 = 1e4 * (0.5 + jax.random.uniform(key, (nsys,)))
-    k3 = 3e7 * 10.0 ** jax.random.uniform(jax.random.PRNGKey(1), (nsys,),
-                                          minval=-1.0, maxval=1.0)
+    k1, k2, k3 = robertson_rates(nsys)
 
     def f(t, y):  # y: (nsys, 3)
         a, b, c = y[:, 0], y[:, 1], y[:, 2]
@@ -57,11 +67,7 @@ def batched_robertson_soa(nsys: int):
     AoS form's, only the stacking axes differ, so trajectories stay
     bitwise-identical to the wrapped-AoS path (tests/test_soa_carry.py).
     """
-    key = jax.random.PRNGKey(0)
-    k1 = 0.04 * jnp.ones((nsys,))
-    k2 = 1e4 * (0.5 + jax.random.uniform(key, (nsys,)))
-    k3 = 3e7 * 10.0 ** jax.random.uniform(jax.random.PRNGKey(1), (nsys,),
-                                          minval=-1.0, maxval=1.0)
+    k1, k2, k3 = robertson_rates(nsys)
 
     def f_soa(t, y):  # y: (3, nsys)
         a, b, c = y[0], y[1], y[2]

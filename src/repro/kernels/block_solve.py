@@ -30,11 +30,15 @@ Two elimination kernels, selected by block size:
 * ``b > UNROLL_MAX_B``  — a **row-tiled** elimination: the b^2 live
   vectors of the unrolled form spill registers at b=16 (256 vectors per
   tile — the BENCH_ensemble.json regression this replaces), so the
-  augmented system instead lives in ONE ``(b, b+1, TN)`` VMEM-resident
-  accumulator and each of the b pivot steps is a handful of whole-array
-  VPU ops (normalize pivot row, mask it out of the factor column, one
-  rank-1 update).  ``ops.py`` additionally shrinks the bundle tile with
-  b^2 so the accumulator stays inside a fixed VMEM budget.
+  matrix instead lives in ONE ``(b, b, TN)`` VMEM-resident accumulator
+  and each of the b pivot steps is a handful of whole-array VPU ops
+  (normalize pivot row, mask it out of the factor column, one rank-1
+  update, and a select that writes the pivot row back — Pallas TPU has
+  no scatter, so no ``.at[].set``).  Every intermediate keeps rank 3
+  (``(1, b, TN)`` rows, ``(b, 1, TN)`` columns), so the rank-1 update
+  is a pair of broadcasts and never a sublane reshape.  ``ops.py``
+  additionally shrinks the bundle tile with b^2 so the accumulator
+  stays inside a fixed VMEM budget.
 """
 from __future__ import annotations
 
@@ -42,9 +46,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
-LANE = 128
+from . import LANE, grid_block, resolve_interpret
 
 # largest block size the fully-unrolled kernels handle before register
 # pressure wins over unrolling (b^2 live lane-vectors; 64 at b=8 is
@@ -129,33 +134,42 @@ def _gj_inverse_kernel(a_ref, x_ref, *, b: int, scale_rows: bool):
             x_ref[i, j, :] = R[i][j]
 
 
+def _row_scale(a):
+    """1 / max_j |a[i, j]| per row of every block, as a (b, 1, TN)
+    column (the diagonal-scaling variant's D)."""
+    return 1.0 / jnp.maximum(jnp.max(jnp.abs(a), axis=1, keepdims=True),
+                             1e-30)
+
+
 def _gj_tiled_kernel(a_ref, r_ref, x_ref, *, b: int, scale_rows: bool):
     """Row-tiled Gauss-Jordan for large blocks (b > UNROLL_MAX_B).
 
-    The augmented system [A | r] lives in one (b, b+1, TN) accumulator;
-    each pivot step is three whole-array ops instead of b^2 per-entry
-    register updates, so the live set is O(b*TN) (one pivot row + one
-    factor column) rather than O(b^2*TN).  The accumulator is held as a
-    functional value: Mosaic materializes it in VMEM either way, and
-    under interpret emulation an explicit ``scratch_shapes`` ref
-    measures 3-7x slower (every ref op round-trips the interpreter's
-    state), which would mask the very regression this kernel fixes.
+    a_ref: (b, b, TN);  r_ref / x_ref: (b, 1, TN) (ops.py passes the
+    right-hand side with a unit sublane axis so it broadcasts against
+    the factor column without a reshape).  Each pivot step is a rank-1
+    update of A and r plus two selects that write the normalized pivot
+    row back, so the live set is O(b*TN) (one pivot row + one factor
+    column) rather than the unrolled kernel's O(b^2*TN) registers.  The
+    accumulator is held as a functional value: Mosaic materializes it
+    in VMEM either way, and under interpret emulation an explicit
+    ``scratch_shapes`` ref measures 3-7x slower.
     """
     a = a_ref[...]
     rr = r_ref[...]
     if scale_rows:
-        inv_m = 1.0 / jnp.maximum(jnp.max(jnp.abs(a), axis=1), 1e-30)
-        a = a * inv_m[:, None, :]
+        inv_m = _row_scale(a)
+        a = a * inv_m
         rr = rr * inv_m
-    S = jnp.concatenate([a, rr[:, None, :]], axis=1)    # (b, b+1, TN)
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (b, 1), 0)
+    rows = lax.broadcasted_iota(jnp.int32, (b, 1, 1), 0)
     for k in range(b):
-        inv = 1.0 / S[k, k, :]
-        rowk = S[k, :, :] * inv[None, :]                # normalized pivot row
-        f = jnp.where(row_ids == k, 0.0, S[:, k, :])    # factor column
-        S = S - f[:, None, :] * rowk[None, :, :]        # rank-1 eliminate
-        S = S.at[k, :, :].set(rowk)
-    x_ref[...] = S[:, b, :]
+        pivot_row = rows == k
+        inv = 1.0 / a[k:k + 1, k:k + 1, :]              # (1, 1, TN)
+        rowk = a[k:k + 1] * inv                         # (1, b, TN)
+        rk = rr[k:k + 1] * inv                          # (1, 1, TN)
+        f = jnp.where(pivot_row, 0.0, a[:, k:k + 1, :])  # (b, 1, TN)
+        a = jnp.where(pivot_row, rowk, a - f * rowk)
+        rr = jnp.where(pivot_row, rk, rr - f * rk)
+    x_ref[...] = rr
 
 
 def _gj_tiled_inverse_kernel(a_ref, x_ref, *, b: int, scale_rows: bool):
@@ -170,28 +184,32 @@ def _gj_tiled_inverse_kernel(a_ref, x_ref, *, b: int, scale_rows: bool):
     """
     a = a_ref[...]
     if scale_rows:
-        inv_m = 1.0 / jnp.maximum(jnp.max(jnp.abs(a), axis=1), 1e-30)
-        a = a * inv_m[:, None, :]                       # (b, b, TN)
+        # rows of A are pre-scaled by D = diag(inv_m): S = (D A)^-1
+        # = A^-1 D^-1, so the COLUMNS are post-scaled below.  The same
+        # scales are needed once as a column (b, 1, TN) and once laid
+        # along the columns (1, b, TN); both come straight from the
+        # reduction, with no transpose.
+        inv_m = _row_scale(a)
+        inv_cols = 1.0 / jnp.maximum(jnp.max(jnp.abs(a), axis=1),
+                                     1e-30)[None]
+        a = a * inv_m
     S = a
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (b, 1), 0)
+    rows = lax.broadcasted_iota(jnp.int32, (b, 1, 1), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (1, b, 1), 1)
     for k in range(b):
-        inv = 1.0 / S[k, k, :]
-        rowk = jnp.where(row_ids == k, inv[None, :],
-                         S[k, :, :] * inv[None, :])     # (b, TN), col-indexed
-        f = jnp.where(row_ids == k, 0.0, S[:, k, :])
-        S = S - f[:, None, :] * rowk[None, :, :]
-        S = S.at[k, :, :].set(rowk)
-        S = S.at[:, k, :].set(jnp.where(row_ids == k, inv[None, :],
-                                        -f * inv[None, :]))
+        pivot_row = rows == k
+        inv = 1.0 / S[k:k + 1, k:k + 1, :]              # (1, 1, TN)
+        rowk = jnp.where(cols == k, inv, S[k:k + 1] * inv)   # (1, b, TN)
+        f = jnp.where(pivot_row, 0.0, S[:, k:k + 1, :])      # (b, 1, TN)
+        S = jnp.where(pivot_row, rowk, S - f * rowk)
+        S = jnp.where(cols == k, jnp.where(pivot_row, inv, -f * inv), S)
     if scale_rows:
-        # rows of A were pre-scaled by D = diag(inv_m):  S = (D A)^-1
-        # = A^-1 D^-1, so post-scale the COLUMNS to recover A^-1
-        S = S * inv_m[None, :, :]
+        S = S * inv_cols
     x_ref[...] = S
 
 
 def block_inverse_soa(A: jnp.ndarray, *, batch_tile: int = 4 * LANE,
-                      interpret: bool = True,
+                      interpret=None,
                       scale_rows: bool = True) -> jnp.ndarray:
     """Invert every block: A:(b,b,NB) -> Ainv:(b,b,NB), NB % tile == 0
     (ops.py pads).  b <= UNROLL_MAX_B uses the unrolled [A | I] kernel
@@ -205,18 +223,19 @@ def block_inverse_soa(A: jnp.ndarray, *, batch_tile: int = 4 * LANE,
     kern = _gj_inverse_kernel if b <= UNROLL_MAX_B \
         else _gj_tiled_inverse_kernel
     kernel = functools.partial(kern, b=b, scale_rows=scale_rows)
+    blocks = grid_block((b, b, batch_tile))
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((b, b, batch_tile), lambda g: (0, 0, g))],
-        out_specs=pl.BlockSpec((b, b, batch_tile), lambda g: (0, 0, g)),
+        in_specs=[blocks],
+        out_specs=blocks,
         out_shape=jax.ShapeDtypeStruct((b, b, NB), A.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(A)
 
 
 def block_solve_soa(A: jnp.ndarray, r: jnp.ndarray, *,
-                    batch_tile: int = 4 * LANE, interpret: bool = True,
+                    batch_tile: int = 4 * LANE, interpret=None,
                     scale_rows: bool = True) -> jnp.ndarray:
     """Solve with SoA layout A:(b,b,NB), r:(b,NB) -> x:(b,NB).
 
@@ -224,23 +243,25 @@ def block_solve_soa(A: jnp.ndarray, r: jnp.ndarray, *,
     program owns a (b, b, batch_tile) VMEM tile: for b=8, tile=512 that
     is 8*8*512*4B = 128 KiB of A — comfortably inside ~16 MiB VMEM.
     b > UNROLL_MAX_B routes to the row-tiled kernel, whose (b, b+1,
-    tile) augmented accumulator ops.py keeps under GJ_VMEM_BYTES by
-    shrinking the tile with b^2.
+    tile) working set (matrix plus right-hand side) ops.py keeps under
+    GJ_VMEM_BYTES by shrinking the tile with b^2.
     """
     b, b2, NB = A.shape
     assert b == b2 and r.shape == (b, NB)
     assert NB % batch_tile == 0, (NB, batch_tile)
     grid = (NB // batch_tile,)
-    kern = _gj_kernel if b <= UNROLL_MAX_B else _gj_tiled_kernel
+    tiled = b > UNROLL_MAX_B
+    kern = _gj_tiled_kernel if tiled else _gj_kernel
     kernel = functools.partial(kern, b=b, scale_rows=scale_rows)
-    return pl.pallas_call(
+    # the row-tiled kernel takes r as (b, 1, NB), see _gj_tiled_kernel
+    rshape = (b, 1) if tiled else (b,)
+    rhs = grid_block(rshape + (batch_tile,))
+    x = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((b, b, batch_tile), lambda g: (0, 0, g)),
-            pl.BlockSpec((b, batch_tile), lambda g: (0, g)),
-        ],
-        out_specs=pl.BlockSpec((b, batch_tile), lambda g: (0, g)),
-        out_shape=jax.ShapeDtypeStruct((b, NB), A.dtype),
-        interpret=interpret,
-    )(A, r)
+        in_specs=[grid_block((b, b, batch_tile)), rhs],
+        out_specs=rhs,
+        out_shape=jax.ShapeDtypeStruct(rshape + (NB,), A.dtype),
+        interpret=resolve_interpret(interpret),
+    )(A, r.reshape(rshape + (NB,)))
+    return x.reshape(b, NB)

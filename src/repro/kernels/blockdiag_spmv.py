@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANE = 128
+from . import LANE, grid_block, resolve_interpret
 
 
 def _spmv_kernel(a_ref, x_ref, y_ref, *, b: int):
@@ -27,7 +27,7 @@ def _spmv_kernel(a_ref, x_ref, y_ref, *, b: int):
 
 def blockdiag_spmv_soa(A: jnp.ndarray, x: jnp.ndarray, *,
                        batch_tile: int = 4 * LANE,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret=None) -> jnp.ndarray:
     b, b2, NB = A.shape
     assert b == b2 and x.shape == (b, NB)
     assert NB % batch_tile == 0
@@ -36,11 +36,9 @@ def blockdiag_spmv_soa(A: jnp.ndarray, x: jnp.ndarray, *,
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((b, b, batch_tile), lambda g: (0, 0, g)),
-            pl.BlockSpec((b, batch_tile), lambda g: (0, g)),
-        ],
-        out_specs=pl.BlockSpec((b, batch_tile), lambda g: (0, g)),
+        in_specs=[grid_block((b, b, batch_tile)),
+                  grid_block((b, batch_tile))],
+        out_specs=grid_block((b, batch_tile)),
         out_shape=jax.ShapeDtypeStruct((b, NB), A.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(A, x)

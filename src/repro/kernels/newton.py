@@ -25,6 +25,10 @@ each is exactly one pass:
 
 Like the block kernels, the n (state) axis rides the sublanes and is
 small/static; ``ops.py`` pads the batch axis to the bundle tile.
+Per-system scalars (gamma, the masks, the WRMS outputs) travel as
+``(1, NB)`` rows: a rank-1 ``(NB,)`` operand gets XLA's 1-D tiled
+layout, which Mosaic refuses unless the bundle tile happens to match
+it.
 """
 from __future__ import annotations
 
@@ -34,18 +38,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANE = 128
+from . import LANE, grid_block, resolve_interpret
+
+
+def _row(v: jnp.ndarray) -> jnp.ndarray:
+    """(NB,) per-system scalars -> the (1, NB) row the kernels take."""
+    return v.reshape(1, v.shape[0])
 
 
 def _newton_residual_kernel(z_ref, f_ref, psi_ref, gam_ref, out_ref, *,
                             negate: bool):
-    g = z_ref[...] - gam_ref[...][None, :] * f_ref[...] - psi_ref[...]
+    g = z_ref[...] - gam_ref[...] * f_ref[...] - psi_ref[...]
     out_ref[...] = -g if negate else g
 
 
 def newton_residual(z: jnp.ndarray, fval: jnp.ndarray, psi: jnp.ndarray,
                     gamma: jnp.ndarray, *, batch_tile: int = 4 * LANE,
-                    interpret: bool = True,
+                    interpret=None,
                     negate: bool = False) -> jnp.ndarray:
     """Fused g = z - gamma*f - psi; all of z/f/psi are (n, NB), gamma is
     (NB,).  ``negate=True`` returns -g (the Newton rhs) in the same
@@ -53,35 +62,30 @@ def newton_residual(z: jnp.ndarray, fval: jnp.ndarray, psi: jnp.ndarray,
     n, NB = z.shape
     assert fval.shape == (n, NB) and psi.shape == (n, NB)
     assert gamma.shape == (NB,) and NB % batch_tile == 0
-    grid = (NB // batch_tile,)
     kernel = functools.partial(_newton_residual_kernel, negate=negate)
+    state = grid_block((n, batch_tile))
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n, batch_tile), lambda g: (0, g)),
-            pl.BlockSpec((n, batch_tile), lambda g: (0, g)),
-            pl.BlockSpec((n, batch_tile), lambda g: (0, g)),
-            pl.BlockSpec((batch_tile,), lambda g: (g,)),
-        ],
-        out_specs=pl.BlockSpec((n, batch_tile), lambda g: (0, g)),
+        grid=(NB // batch_tile,),
+        in_specs=[state, state, state, grid_block((1, batch_tile))],
+        out_specs=state,
         out_shape=jax.ShapeDtypeStruct((n, NB), z.dtype),
-        interpret=interpret,
-    )(z, fval, psi, gamma)
+        interpret=resolve_interpret(interpret),
+    )(z, fval, psi, _row(gamma))
 
 
 def _masked_update_wrms_kernel(z_ref, dz_ref, w_ref, m_ref, zout_ref,
                                dn_ref, *, n: int):
-    m = m_ref[...] > 0.5                     # float mask on the lanes
+    m = m_ref[...] > 0.5                     # (1, TN) float mask
     dz = dz_ref[...]
-    zout_ref[...] = jnp.where(m[None, :], z_ref[...] + dz, z_ref[...])
+    zout_ref[...] = jnp.where(m, z_ref[...] + dz, z_ref[...])
     t = dz * w_ref[...]
-    dn_ref[...] = jnp.sqrt(jnp.sum(t * t, axis=0) / n)
+    dn_ref[...] = jnp.sqrt(jnp.sum(t * t, axis=0, keepdims=True) / n)
 
 
 def masked_update_wrms(z: jnp.ndarray, dz: jnp.ndarray, w: jnp.ndarray,
                        mask: jnp.ndarray, *, batch_tile: int = 4 * LANE,
-                       interpret: bool = True):
+                       interpret=None):
     """Fused masked iterate update + per-system WRMS of the correction.
 
     z/dz/w: (n, NB), mask: (NB,) (nonzero = update) ->
@@ -93,48 +97,42 @@ def masked_update_wrms(z: jnp.ndarray, dz: jnp.ndarray, w: jnp.ndarray,
     n, NB = z.shape
     assert dz.shape == (n, NB) and w.shape == (n, NB)
     assert mask.shape == (NB,) and NB % batch_tile == 0
-    grid = (NB // batch_tile,)
     kernel = functools.partial(_masked_update_wrms_kernel, n=n)
-    return pl.pallas_call(
+    state = grid_block((n, batch_tile))
+    lanes = grid_block((1, batch_tile))
+    z_new, dn = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n, batch_tile), lambda g: (0, g)),
-            pl.BlockSpec((n, batch_tile), lambda g: (0, g)),
-            pl.BlockSpec((n, batch_tile), lambda g: (0, g)),
-            pl.BlockSpec((batch_tile,), lambda g: (g,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((n, batch_tile), lambda g: (0, g)),
-            pl.BlockSpec((batch_tile,), lambda g: (g,)),
-        ],
+        grid=(NB // batch_tile,),
+        in_specs=[state, state, state, lanes],
+        out_specs=[state, lanes],
         out_shape=[
             jax.ShapeDtypeStruct((n, NB), z.dtype),
-            jax.ShapeDtypeStruct((NB,), z.dtype),
+            jax.ShapeDtypeStruct((1, NB), z.dtype),
         ],
-        interpret=interpret,
-    )(z, dz, w, mask)
+        interpret=resolve_interpret(interpret),
+    )(z, dz, w, _row(mask))
+    return z_new, dn.reshape(NB)
 
 
 def _history_rescale_kernel(w_ref, z_ref, a_ref, out_ref, *, q1: int):
-    act = a_ref[...] > 0.5
+    act = a_ref[...] > 0.5                   # (1, TN)
 
-    @pl.when(jnp.any(act))
+    @pl.when(jnp.max(a_ref[...]) > 0.5)
     def _():
         for j in range(q1):
-            acc = w_ref[j, 0, :][None, :] * z_ref[0]
+            acc = w_ref[j, 0:1, :] * z_ref[0]
             for i in range(1, q1):
-                acc = acc + w_ref[j, i, :][None, :] * z_ref[i]
-            out_ref[j, :, :] = jnp.where(act[None, :], acc, z_ref[j])
+                acc = acc + w_ref[j, i:i + 1, :] * z_ref[i]
+            out_ref[j] = jnp.where(act, acc, z_ref[j])
 
-    @pl.when(jnp.logical_not(jnp.any(act)))
+    @pl.when(jnp.max(a_ref[...]) <= 0.5)
     def _():
         out_ref[...] = z_ref[...]
 
 
 def history_rescale(W: jnp.ndarray, Z: jnp.ndarray, active: jnp.ndarray,
                     *, batch_tile: int = 4 * LANE,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret=None) -> jnp.ndarray:
     """Lane-parallel Lagrange history rebuild with inactive short-circuit.
 
     W: (q1, q1, NB) per-system rescale matrices, Z: (q1, n, NB) history,
@@ -148,44 +146,39 @@ def history_rescale(W: jnp.ndarray, Z: jnp.ndarray, active: jnp.ndarray,
     _, n, _ = Z.shape
     assert q1 == q1b and Z.shape == (q1, n, NB)
     assert active.shape == (NB,) and NB % batch_tile == 0
-    grid = (NB // batch_tile,)
     kernel = functools.partial(_history_rescale_kernel, q1=q1)
+    hist = grid_block((q1, n, batch_tile))
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((q1, q1, batch_tile), lambda g: (0, 0, g)),
-            pl.BlockSpec((q1, n, batch_tile), lambda g: (0, 0, g)),
-            pl.BlockSpec((batch_tile,), lambda g: (g,)),
-        ],
-        out_specs=pl.BlockSpec((q1, n, batch_tile), lambda g: (0, 0, g)),
+        grid=(NB // batch_tile,),
+        in_specs=[grid_block((q1, q1, batch_tile)), hist,
+                  grid_block((1, batch_tile))],
+        out_specs=hist,
         out_shape=jax.ShapeDtypeStruct((q1, n, NB), Z.dtype),
-        interpret=interpret,
-    )(W, Z, active)
+        interpret=resolve_interpret(interpret),
+    )(W, Z, _row(active))
 
 
 def _wrms_soa_kernel(v_ref, w_ref, out_ref, *, n: int):
     t = v_ref[...] * w_ref[...]
-    out_ref[...] = jnp.sqrt(jnp.sum(t * t, axis=0) / n)
+    out_ref[...] = jnp.sqrt(jnp.sum(t * t, axis=0, keepdims=True) / n)
 
 
 def wrms_soa(v: jnp.ndarray, w: jnp.ndarray, *,
              batch_tile: int = 4 * LANE,
-             interpret: bool = True) -> jnp.ndarray:
+             interpret=None) -> jnp.ndarray:
     """Per-system WRMS: v/w (n, NB) -> (NB,), one fused pass (the
     sublane reduction stays inside the tile, so no partials)."""
     n, NB = v.shape
     assert w.shape == (n, NB) and NB % batch_tile == 0
-    grid = (NB // batch_tile,)
     kernel = functools.partial(_wrms_soa_kernel, n=n)
-    return pl.pallas_call(
+    state = grid_block((n, batch_tile))
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n, batch_tile), lambda g: (0, g)),
-            pl.BlockSpec((n, batch_tile), lambda g: (0, g)),
-        ],
-        out_specs=pl.BlockSpec((batch_tile,), lambda g: (g,)),
-        out_shape=jax.ShapeDtypeStruct((NB,), v.dtype),
-        interpret=interpret,
+        grid=(NB // batch_tile,),
+        in_specs=[state, state],
+        out_specs=grid_block((1, batch_tile)),
+        out_shape=jax.ShapeDtypeStruct((1, NB), v.dtype),
+        interpret=resolve_interpret(interpret),
     )(v, w)
+    return out.reshape(NB)

@@ -1,9 +1,10 @@
 """jit'd public wrappers for the Pallas kernels (padding, layout, dispatch).
 
 Callers use these; the raw kernels live in their own modules and the
-pure-jnp oracles in ref.py.  On this CPU container ``interpret=True``
-runs the kernel bodies in Python for validation; on TPU deployments the
-same entry points compile to Mosaic (``interpret=False`` via ExecPolicy).
+pure-jnp oracles in ref.py.  ``interpret=None`` (the default) derives
+the mode from the backend: the kernels compile to Mosaic on a TPU and
+run interpreted anywhere else (the CPU test suite validates them that
+way); ``True``/``False`` force either mode.
 """
 from __future__ import annotations
 
@@ -12,13 +13,15 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from . import LANE, resolve_interpret
 from . import block_solve as _bs
 from . import blockdiag_spmv as _sp
 from . import newton as _nw
 from . import sparse as _sx
 from . import vecops as _vo
 
-LANE = 128
+#: f32 rows per (8, 128) vreg tile
+SUBLANE = 8
 
 # VMEM budget for the row-tiled Gauss-Jordan accumulator (compiled
 # mode): the (b, width, tile) working set is kept under this many
@@ -32,6 +35,18 @@ GJ_VMEM_BYTES = 2 * 1024 * 1024
 def _lane_ceil(n: int) -> int:
     """Smallest lane-aligned size >= n (tile clamp for short vectors)."""
     return max(LANE, -(-n // LANE) * LANE)
+
+
+def _vec_tile(n: int, tile: int) -> int:
+    """Tile for a flat vector of ``n`` elements: the whole lane-padded
+    vector when it fits in ``tile``, else ``tile`` rounded up to whole
+    (SUBLANE, LANE) vreg tiles — a block that does not span the whole
+    axis must tile its second-minor dimension by SUBLANE rows."""
+    n = _lane_ceil(n)
+    if n <= tile:
+        return n
+    step = SUBLANE * LANE
+    return -(-tile // step) * step
 
 
 def _pad_to(x: jnp.ndarray, mult: int, axis: int, fill=0.0):
@@ -60,7 +75,7 @@ def _batch_tile(nb: int, batch_tile: int) -> int:
 
 
 def _gj_batch_tile(nb: int, batch_tile: int, *, b: int, width: int,
-                   itemsize: int, interpret: bool,
+                   itemsize: int, interpret,
                    vmem_bytes=None) -> int:
     """Bundle tile for the Gauss-Jordan kernels: :func:`_batch_tile`
     with, in compiled mode, the requested tile first clamped so the
@@ -72,7 +87,7 @@ def _gj_batch_tile(nb: int, batch_tile: int, *, b: int, width: int,
     the cost-model dispatch layer passes the roofline device table's
     budget here so the clamp is a policy-visible decision rather than a
     module constant."""
-    if not interpret:
+    if not resolve_interpret(interpret):
         budget = GJ_VMEM_BYTES if vmem_bytes is None else vmem_bytes
         cap = budget // (itemsize * b * width)
         batch_tile = min(batch_tile, max(LANE, cap // LANE * LANE))
@@ -93,7 +108,7 @@ def _pad_blocks_identity(Ap: jnp.ndarray, nb: int) -> jnp.ndarray:
 @functools.partial(jax.jit, static_argnames=("batch_tile", "interpret",
                                              "scale_rows", "vmem_bytes"))
 def block_solve(A: jnp.ndarray, r: jnp.ndarray, *, batch_tile: int = 4 * LANE,
-                interpret: bool = True, scale_rows: bool = True,
+                interpret=None, scale_rows: bool = True,
                 vmem_bytes=None):
     """Batched block solve, AoS API: A:(nb,b,b), r:(nb,b) -> x:(nb,b).
 
@@ -120,7 +135,7 @@ def block_solve(A: jnp.ndarray, r: jnp.ndarray, *, batch_tile: int = 4 * LANE,
 @functools.partial(jax.jit, static_argnames=("batch_tile", "interpret",
                                              "scale_rows", "vmem_bytes"))
 def block_solve_soa(A: jnp.ndarray, r: jnp.ndarray, *,
-                    batch_tile: int = 4 * LANE, interpret: bool = True,
+                    batch_tile: int = 4 * LANE, interpret=None,
                     scale_rows: bool = True, vmem_bytes=None):
     """SoA API (lane-major batch): A:(b,b,NB), r:(b,NB) -> x:(b,NB)."""
     b, _, nb = A.shape
@@ -138,7 +153,7 @@ def block_solve_soa(A: jnp.ndarray, r: jnp.ndarray, *,
 @functools.partial(jax.jit, static_argnames=("batch_tile", "interpret",
                                              "scale_rows", "vmem_bytes"))
 def block_inverse_soa(A: jnp.ndarray, *, batch_tile: int = 4 * LANE,
-                      interpret: bool = True, scale_rows: bool = True,
+                      interpret=None, scale_rows: bool = True,
                       vmem_bytes=None):
     """Per-block inverse, SoA layout: A:(b,b,NB) -> A^{-1}:(b,b,NB).
 
@@ -158,33 +173,35 @@ def block_inverse_soa(A: jnp.ndarray, *, batch_tile: int = 4 * LANE,
 
 @functools.partial(jax.jit, static_argnames=("block_elems", "interpret"))
 def linear_combination(coeffs: jnp.ndarray, X: jnp.ndarray, *,
-                       block_elems: int = 8 * LANE, interpret: bool = True):
+                       block_elems: int = 8 * LANE, interpret=None):
     """Fused Z = sum_k coeffs[k] X[k];  X:(K, N) any N (padded inside)."""
     K, N = X.shape
-    Xp, _ = _pad_to(X, block_elems, axis=1)
-    z = _vo.linear_combination(coeffs, Xp, block_elems=block_elems,
+    tile = _vec_tile(N, block_elems)
+    Xp, _ = _pad_to(X, tile, axis=1)
+    z = _vo.linear_combination(coeffs, Xp, block_elems=tile,
                                interpret=interpret)
     return z[:N]
 
 
 @functools.partial(jax.jit, static_argnames=("block_elems", "interpret"))
 def scale_add_multi(coeffs: jnp.ndarray, x: jnp.ndarray, Y: jnp.ndarray, *,
-                    block_elems: int = 8 * LANE, interpret: bool = True):
+                    block_elems: int = 8 * LANE, interpret=None):
     """Fused Z[k] = coeffs[k]*x + Y[k];  x:(N,), Y:(K,N) any N."""
     K, N = Y.shape
-    xp, _ = _pad_to(x, block_elems, axis=0)
-    Yp, _ = _pad_to(Y, block_elems, axis=1)
-    z = _vo.scale_add_multi(coeffs, xp, Yp, block_elems=block_elems,
+    tile = _vec_tile(N, block_elems)
+    xp, _ = _pad_to(x, tile, axis=0)
+    Yp, _ = _pad_to(Y, tile, axis=1)
+    z = _vo.scale_add_multi(coeffs, xp, Yp, block_elems=tile,
                             interpret=interpret)
     return z[:, :N]
 
 
 @functools.partial(jax.jit, static_argnames=("reduce_tile", "interpret"))
 def wrms_norm(x: jnp.ndarray, w: jnp.ndarray, *, reduce_tile: int = 64 * LANE,
-              interpret: bool = True):
+              interpret=None):
     """Fused WRMS norm of 1-D x with weights w (BlockReduce policy)."""
     (N,) = x.shape
-    tile = min(reduce_tile, _lane_ceil(N))
+    tile = _vec_tile(N, reduce_tile)
     xp, _ = _pad_to(x, tile, axis=0)
     wp, _ = _pad_to(w, tile, axis=0)   # pad weights with 0 -> no contribution
     parts = _vo.wrms_partial(xp, wp, reduce_tile=tile, interpret=interpret)
@@ -193,9 +210,9 @@ def wrms_norm(x: jnp.ndarray, w: jnp.ndarray, *, reduce_tile: int = 64 * LANE,
 
 @functools.partial(jax.jit, static_argnames=("reduce_tile", "interpret"))
 def dot(x: jnp.ndarray, y: jnp.ndarray, *, reduce_tile: int = 64 * LANE,
-        interpret: bool = True):
+        interpret=None):
     (N,) = x.shape
-    tile = min(reduce_tile, _lane_ceil(N))
+    tile = _vec_tile(N, reduce_tile)
     xp, _ = _pad_to(x, tile, axis=0)
     yp, _ = _pad_to(y, tile, axis=0)
     parts = _vo.dot_partial(xp, yp, reduce_tile=tile, interpret=interpret)
@@ -204,11 +221,11 @@ def dot(x: jnp.ndarray, y: jnp.ndarray, *, reduce_tile: int = 64 * LANE,
 
 @functools.partial(jax.jit, static_argnames=("reduce_tile", "interpret"))
 def wrms_ss(x: jnp.ndarray, w: jnp.ndarray, *, reduce_tile: int = 64 * LANE,
-            interpret: bool = True):
+            interpret=None):
     """Raw sum((x*w)^2) of 1-D x — the per-leaf partial the dispatch
     layer accumulates across pytree leaves before the final sqrt(/N)."""
     (N,) = x.shape
-    tile = min(reduce_tile, _lane_ceil(N))
+    tile = _vec_tile(N, reduce_tile)
     xp, _ = _pad_to(x, tile, axis=0)
     wp, _ = _pad_to(w, tile, axis=0)
     parts = _vo.wrms_partial(xp, wp, reduce_tile=tile, interpret=interpret)
@@ -217,10 +234,10 @@ def wrms_ss(x: jnp.ndarray, w: jnp.ndarray, *, reduce_tile: int = 64 * LANE,
 
 @functools.partial(jax.jit, static_argnames=("reduce_tile", "interpret"))
 def wrms_mask_ss(x: jnp.ndarray, w: jnp.ndarray, m: jnp.ndarray, *,
-                 reduce_tile: int = 64 * LANE, interpret: bool = True):
+                 reduce_tile: int = 64 * LANE, interpret=None):
     """Raw sum((x*w*m)^2) of 1-D x (masked WRMS partial)."""
     (N,) = x.shape
-    tile = min(reduce_tile, _lane_ceil(N))
+    tile = _vec_tile(N, reduce_tile)
     xp, _ = _pad_to(x, tile, axis=0)
     wp, _ = _pad_to(w, tile, axis=0)
     mp, _ = _pad_to(m, tile, axis=0)
@@ -231,10 +248,10 @@ def wrms_mask_ss(x: jnp.ndarray, w: jnp.ndarray, m: jnp.ndarray, *,
 
 @functools.partial(jax.jit, static_argnames=("reduce_tile", "interpret"))
 def wrms_norm_mask(x: jnp.ndarray, w: jnp.ndarray, m: jnp.ndarray, *,
-                   reduce_tile: int = 64 * LANE, interpret: bool = True):
+                   reduce_tile: int = 64 * LANE, interpret=None):
     """Masked WRMS norm of 1-D x: sqrt(sum((x*w*m)^2)/N)."""
     (N,) = x.shape
-    tile = min(reduce_tile, _lane_ceil(N))
+    tile = _vec_tile(N, reduce_tile)
     xp, _ = _pad_to(x, tile, axis=0)
     wp, _ = _pad_to(w, tile, axis=0)   # zero weights -> no contribution
     mp, _ = _pad_to(m, tile, axis=0)
@@ -245,20 +262,20 @@ def wrms_norm_mask(x: jnp.ndarray, w: jnp.ndarray, m: jnp.ndarray, *,
 
 @functools.partial(jax.jit, static_argnames=("reduce_tile", "interpret"))
 def dot_prod_multi(x: jnp.ndarray, Y: jnp.ndarray, *,
-                   reduce_tile: int = 64 * LANE, interpret: bool = True):
+                   reduce_tile: int = 64 * LANE, interpret=None):
     """d_k = <x, Y[k]>;  x:(N,), Y:(K,N) -> (K,), single fused pass."""
     (N,) = x.shape
-    tile = min(reduce_tile, _lane_ceil(N))
+    tile = _vec_tile(N, reduce_tile)
     xp, _ = _pad_to(x, tile, axis=0)
     Yp, _ = _pad_to(Y, tile, axis=1)
     parts = _vo.multi_dot_partial(xp, Yp, reduce_tile=tile,
                                   interpret=interpret)
-    return jnp.sum(parts, axis=1)
+    return jnp.sum(parts, axis=(0, 2))
 
 
 @functools.partial(jax.jit, static_argnames=("batch_tile", "interpret"))
 def blockdiag_spmv(A: jnp.ndarray, x: jnp.ndarray, *,
-                   batch_tile: int = 4 * LANE, interpret: bool = True):
+                   batch_tile: int = 4 * LANE, interpret=None):
     """AoS API: A:(nb,b,b), x:(nb,b) -> y:(nb,b)."""
     nb, b, _ = A.shape
     tile = _batch_tile(nb, batch_tile)
@@ -272,7 +289,7 @@ def blockdiag_spmv(A: jnp.ndarray, x: jnp.ndarray, *,
 
 @functools.partial(jax.jit, static_argnames=("batch_tile", "interpret"))
 def blockdiag_spmv_soa(A: jnp.ndarray, x: jnp.ndarray, *,
-                       batch_tile: int = 4 * LANE, interpret: bool = True):
+                       batch_tile: int = 4 * LANE, interpret=None):
     """SoA API: A:(b,b,NB), x:(b,NB) -> y:(b,NB); pads NB to the bundle
     tile (zero-padded systems produce zeros, sliced off)."""
     b, _, nb = A.shape
@@ -292,7 +309,7 @@ def blockdiag_spmv_soa(A: jnp.ndarray, x: jnp.ndarray, *,
                                              "negate"))
 def newton_residual_soa(z: jnp.ndarray, fval: jnp.ndarray,
                         psi: jnp.ndarray, gamma: jnp.ndarray, *,
-                        batch_tile: int = 4 * LANE, interpret: bool = True,
+                        batch_tile: int = 4 * LANE, interpret=None,
                         negate: bool = False):
     """Fused g = z - gamma*f - psi (``negate=True`` -> -g, the Newton
     rhs); z/f/psi (n, NB), gamma (NB,), any NB (padded inside)."""
@@ -311,7 +328,7 @@ def newton_residual_soa(z: jnp.ndarray, fval: jnp.ndarray,
 def masked_update_wrms_soa(z: jnp.ndarray, dz: jnp.ndarray, w: jnp.ndarray,
                            mask: jnp.ndarray, *,
                            batch_tile: int = 4 * LANE,
-                           interpret: bool = True):
+                           interpret=None):
     """Fused masked z += dz and per-system WRMS of dz: z/dz/w (n, NB),
     mask (NB,) -> (z_new, dn); padded systems report dn = 0."""
     n, nb = z.shape
@@ -329,7 +346,7 @@ def masked_update_wrms_soa(z: jnp.ndarray, dz: jnp.ndarray, w: jnp.ndarray,
 def history_rescale_soa(W: jnp.ndarray, Z: jnp.ndarray,
                         active: jnp.ndarray, *,
                         batch_tile: int = 4 * LANE,
-                        interpret: bool = True):
+                        interpret=None):
     """Masked Lagrange history rebuild: W (q1,q1,NB), Z (q1,n,NB),
     active (NB,) -> Z_new; padded systems are inactive (Z copied)."""
     q1, _, nb = W.shape
@@ -344,7 +361,7 @@ def history_rescale_soa(W: jnp.ndarray, Z: jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("batch_tile", "interpret"))
 def wrms_soa(v: jnp.ndarray, w: jnp.ndarray, *,
-             batch_tile: int = 4 * LANE, interpret: bool = True):
+             batch_tile: int = 4 * LANE, interpret=None):
     """Per-system WRMS over the state axis: v/w (n, NB) -> (NB,)."""
     n, nb = v.shape
     tile = _batch_tile(nb, batch_tile)
@@ -363,7 +380,7 @@ def wrms_soa(v: jnp.ndarray, w: jnp.ndarray, *,
                                              "block_elems", "interpret"))
 def csr_spmv(data: jnp.ndarray, x: jnp.ndarray, *, indptr: tuple,
              indices: tuple, block_elems: int = 8 * LANE,
-             interpret: bool = True):
+             interpret=None):
     """y = A @ x for CSR A with a STATIC pattern: data:(nnz,), x:(ncol,).
 
     The pattern is ELL-ized at trace time (host numpy on the static
@@ -398,7 +415,7 @@ def csr_spmv(data: jnp.ndarray, x: jnp.ndarray, *, indptr: tuple,
                                              "batch_tile", "interpret"))
 def bsr_spmv_soa(values: jnp.ndarray, x: jnp.ndarray, *, brows: tuple,
                  bcols: tuple, nblk: int, batch_tile: int = 4 * LANE,
-                 interpret: bool = True):
+                 interpret=None):
     """Ensemble shared-pattern BSR SpMV: values (nnzb, b, b, NB),
     x (nblk, b, NB) -> y (nblk, b, NB); pads the system batch NB to the
     bundle tile (zero-padded systems produce zeros, sliced off)."""
@@ -416,7 +433,7 @@ def bsr_spmv_soa(values: jnp.ndarray, x: jnp.ndarray, *, brows: tuple,
 def bsr_diag_inverse_soa(values: jnp.ndarray, *, brows: tuple,
                          bcols: tuple, nblk: int,
                          batch_tile: int = 4 * LANE,
-                         interpret: bool = True):
+                         interpret=None):
     """Invert every diagonal block of the shared pattern — the
     block-Jacobi psetup: values (nnzb, b, b, NB) -> (b, b, nblk*NB),
     flattened batch block-major (block I of system s at I*NB + s).

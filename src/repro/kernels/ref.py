@@ -2,11 +2,19 @@
 
 Each function must be the semantic ground truth the kernels are tested
 against with assert_allclose over shape/dtype sweeps.
+
+Every contraction asks for ``HIGHEST`` precision: at the default, a TPU
+rounds float32 matmul operands to bfloat16, which leaves too few digits
+for a Newton iteration on stiff blocks (every lane of a float32
+Robertson ensemble then fails to converge).  The CPU ignores the flag.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+
+HIGHEST = lax.Precision.HIGHEST
 
 
 def block_solve_ref(A: jnp.ndarray, r: jnp.ndarray) -> jnp.ndarray:
@@ -24,7 +32,7 @@ def block_solve_soa_ref(A: jnp.ndarray, r: jnp.ndarray) -> jnp.ndarray:
 
 def linear_combination_ref(coeffs: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
     """Z = sum_k c_k X[k];  X:(K,N), coeffs:(K,) -> (N,)."""
-    return jnp.einsum("k,kn->n", coeffs, X)
+    return jnp.einsum("k,kn->n", coeffs, X, precision=HIGHEST)
 
 
 def scale_add_multi_ref(coeffs: jnp.ndarray, x: jnp.ndarray,
@@ -46,16 +54,16 @@ def wrms_mask_partial_ref(x: jnp.ndarray, w: jnp.ndarray,
 
 def dot_prod_multi_ref(x: jnp.ndarray, Y: jnp.ndarray) -> jnp.ndarray:
     """d_k = <x, Y[k]>;  x:(N,), Y:(K,N) -> (K,)."""
-    return Y @ x
+    return jnp.matmul(Y, x, precision=HIGHEST)
 
 
 def dot_ref(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
-    return jnp.vdot(x, y)
+    return jnp.vdot(x, y, precision=HIGHEST)
 
 
 def blockdiag_spmv_soa_ref(A: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """y = blockdiag(A) @ x in SoA; A:(b,b,NB), x:(b,NB) -> y:(b,NB)."""
-    return jnp.einsum("ijn,jn->in", A, x)
+    return jnp.einsum("ijn,jn->in", A, x, precision=HIGHEST)
 
 
 def block_inverse_soa_ref(A: jnp.ndarray) -> jnp.ndarray:
@@ -96,7 +104,8 @@ def history_rescale_soa_ref(W: jnp.ndarray, Z: jnp.ndarray,
     """
     Waos = jnp.transpose(W, (2, 0, 1))
     Zaos = jnp.transpose(Z, (2, 0, 1))
-    R = jnp.transpose(jnp.einsum("sji,sik->sjk", Waos, Zaos), (1, 2, 0))
+    R = jnp.transpose(jnp.einsum("sji,sik->sjk", Waos, Zaos,
+                               precision=HIGHEST), (1, 2, 0))
     return jnp.where(active[None, None, :] != 0, R, Z)
 
 
@@ -122,7 +131,8 @@ def bsr_spmv_soa_ref(values: jnp.ndarray, x: jnp.ndarray, brows, bcols,
     """Shared-pattern ensemble BSR SpMV oracle: values (nnzb, b, b, NB),
     x (nblk, b, NB) -> y (nblk, b, NB)."""
     bc = jnp.asarray(bcols)
-    contrib = jnp.einsum("eijn,ejn->ein", values, x[bc])
+    contrib = jnp.einsum("eijn,ejn->ein", values, x[bc],
+                         precision=HIGHEST)
     return jax.ops.segment_sum(contrib, jnp.asarray(brows),
                                num_segments=nblk)
 

@@ -26,9 +26,10 @@ the Gauss-Jordan inverse kernel from block_solve.py over the flattened
 ``nblk * NB`` batch.
 
 ``ref.py`` holds the pure-jnp oracles both kernels are parity-tested
-against.  The CSR kernel's lane gather (``jnp.take`` from a VMEM-
-resident x) is the one op that leans on newer Mosaic gather support; on
-this container everything runs with ``interpret=True``.
+against.  The BSR kernel compiles for TPU.  The CSR kernel's lane
+gather (``jnp.take`` from a VMEM-resident x) does not: Mosaic lowers
+only 2-D gathers, so :func:`csr_spmv_ell` runs in interpret mode only
+and raises :class:`NotImplementedError` when asked to compile.
 """
 from __future__ import annotations
 
@@ -38,7 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANE = 128
+from . import LANE, grid_block, resolve_interpret, whole_block
 
 
 def _csr_ell_kernel(d_ref, c_ref, x_ref, y_ref, *, kmax: int):
@@ -54,13 +55,20 @@ def _csr_ell_kernel(d_ref, c_ref, x_ref, y_ref, *, kmax: int):
 
 def csr_spmv_ell(data_ell: jnp.ndarray, cols_ell: jnp.ndarray,
                  x: jnp.ndarray, *, row_tile: int = 8 * LANE,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret=None) -> jnp.ndarray:
     """y = A @ x with A in lane-major ELL form.
 
     data_ell : (kmax, NR) — NR lane-padded row count, NR % row_tile == 0
     cols_ell : (kmax, NR) int32 column of each slot (0 where padded)
     x        : (NC,) the full input vector (stays resident per program)
     """
+    if not resolve_interpret(interpret):
+        raise NotImplementedError(
+            "Pallas op csr_spmv (kernel csr_spmv_ell) does not compile "
+            "for TPU: its per-lane gather from the VMEM-resident x is "
+            "not lowered by Mosaic ('Only 2D gather is supported').  "
+            "Route this op to the jnp backend, e.g. "
+            "policy.override(csr_spmv='jnp').")
     kmax, NR = data_ell.shape
     assert cols_ell.shape == (kmax, NR)
     assert NR % row_tile == 0, (NR, row_tile)
@@ -70,14 +78,12 @@ def csr_spmv_ell(data_ell: jnp.ndarray, cols_ell: jnp.ndarray,
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((kmax, row_tile), lambda g: (0, g)),
-            pl.BlockSpec((kmax, row_tile), lambda g: (0, g)),
-            pl.BlockSpec((NC,), lambda g: (0,)),
-        ],
-        out_specs=pl.BlockSpec((row_tile,), lambda g: (g,)),
+        in_specs=[grid_block((kmax, row_tile)),
+                  grid_block((kmax, row_tile)),
+                  whole_block((NC,))],
+        out_specs=grid_block((row_tile,)),
         out_shape=jax.ShapeDtypeStruct((NR,), data_ell.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(data_ell, cols_ell, x)
 
 
@@ -108,7 +114,7 @@ def _bsr_spmv_kernel(v_ref, x_ref, y_ref, *, b: int, nblk: int,
 
 def bsr_spmv_soa(values: jnp.ndarray, x: jnp.ndarray, *, brows: tuple,
                  bcols: tuple, nblk: int, batch_tile: int = 4 * LANE,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret=None) -> jnp.ndarray:
     """y_I = sum_{e: brows[e]=I} A_e @ x_{bcols[e]} for every ensemble
     member: values (nnzb, b, b, NB), x (nblk, b, NB) -> y (nblk, b, NB).
     NB % batch_tile == 0 (ops.py pads; zero-padded systems yield zeros).
@@ -123,11 +129,9 @@ def bsr_spmv_soa(values: jnp.ndarray, x: jnp.ndarray, *, brows: tuple,
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((nnzb, b, b, batch_tile), lambda g: (0, 0, 0, g)),
-            pl.BlockSpec((nblk, b, batch_tile), lambda g: (0, 0, g)),
-        ],
-        out_specs=pl.BlockSpec((nblk, b, batch_tile), lambda g: (0, 0, g)),
+        in_specs=[grid_block((nnzb, b, b, batch_tile)),
+                  grid_block((nblk, b, batch_tile))],
+        out_specs=grid_block((nblk, b, batch_tile)),
         out_shape=jax.ShapeDtypeStruct((nblk, b, NB), values.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(values, x)

@@ -32,7 +32,6 @@ from jax.sharding import PartitionSpec as P
 from .config import ArchConfig
 from . import layers
 
-from repro.parallel.sharding import shard_map_compat
 
 
 def _round_up(x: int, m: int) -> int:
@@ -209,10 +208,10 @@ def moe_ep_apply(p: Dict, cfg: ArchConfig, x: jnp.ndarray, mesh, *,
         return out.reshape(Bl, Sl, d)
 
     w_spec = P(ep_axes if multi_axis else ep_axes[0], None, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
-        out_specs=x_spec,
+        out_specs=x_spec, check_vma=False,
     )
     out = fn(x, p["router"], p["w1"], p["w3"], p["w2"])
     if "shared" in p:

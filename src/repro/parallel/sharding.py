@@ -15,28 +15,11 @@ shapes lower on any mesh without per-arch special-casing.
 """
 from __future__ import annotations
 
-import inspect
 import math
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax>=0.6 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma in
-# jax 0.6; resolve it once so every shard_map call site stays portable
-_SHMAP_CHECK_KW = ("check_vma" if "check_vma" in
-                   inspect.signature(_shard_map).parameters else "check_rep")
-
-
-def shard_map_compat(body, mesh, in_specs, out_specs, check: bool = False):
-    """shard_map across jax versions (check_rep/check_vma rename)."""
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_SHMAP_CHECK_KW: check})
 
 LogicalAxes = Tuple[Optional[str], ...]
 

@@ -20,7 +20,7 @@ def _run_py(code: str, devices: int = 8, timeout=900):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"      # children never touch the chip
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, env=env, timeout=timeout)
     assert out.returncode == 0, out.stdout + "\n" + out.stderr

@@ -268,7 +268,7 @@ def test_bdf_sharded_matches_single_device():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"      # children never touch the chip
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, env=env, timeout=900)
     assert out.returncode == 0, out.stdout + "\n" + out.stderr

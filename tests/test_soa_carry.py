@@ -91,10 +91,10 @@ def _aos_bdf_reference(f, jac, y0, t0, tf, *, order=5,
         W = jax.vmap(_cv._lagrange_matrix)(eta_clip, nvalid)
         Z = jnp.einsum("sji,sik->sjk", W, c.Z)
         qi = c.q - 1
-        alphas = _cv._ALPHA_T[qi].astype(dtype)
-        beta = _cv._BETA_T[qi].astype(dtype)
+        alphas = jnp.asarray(_cv._ALPHA_T, dtype)[qi]
+        beta = jnp.asarray(_cv._BETA_T, dtype)[qi]
         p_pred = jnp.minimum(nvalid, c.q)
-        pred_c = _cv._PREDP_T[p_pred].astype(dtype)
+        pred_c = jnp.asarray(_cv._PREDP_T, dtype)[p_pred]
         y_pred = jnp.einsum("sj,sjk->sk", pred_c, Z)
         psi = -jnp.einsum("sj,sjk->sk", alphas[:, 1:], Z[:, :-1])
         gamma = beta * hs

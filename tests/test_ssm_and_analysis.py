@@ -85,12 +85,11 @@ def test_hlocost_collectives_in_loops():
         y, _ = jax.lax.scan(body, x, None, length=5)
         return y
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     import os
     # single-device "mesh" still emits the loop structure
     mesh = jax.make_mesh((1,), ("i",))
-    g = shard_map(f, mesh=mesh, in_specs=P(None), out_specs=P(None))
+    g = jax.shard_map(f, mesh=mesh, in_specs=P(None), out_specs=P(None))
     txt = jax.jit(g).lower(
         jax.ShapeDtypeStruct((8,), jnp.float32)).compile().as_text()
     res = hlocost.analyze(txt, 1)
