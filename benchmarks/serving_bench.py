@@ -204,8 +204,9 @@ def _validate_prometheus(text: str, m: dict) -> None:
 
 def _profiled_trace_smoke(nreq: int = 96, verbose: bool = True) -> None:
     """A profiled mini-run: every flushed bundle must land queue-wait /
-    compile / execute spans on the profiler timeline, and the exported
-    Chrome trace must be loadable, well-formed JSON."""
+    execute / resolve spans on the profiler timeline, each compile its
+    own, and the exported Chrome trace must be loadable, well-formed
+    JSON."""
     import json as _json
     import os
     import tempfile
@@ -225,11 +226,12 @@ def _profiled_trace_smoke(nreq: int = 96, verbose: bool = True) -> None:
     spans = {}
     for s in srv.ctx.profiler.spans:
         spans.setdefault(s.name, []).append(s)
-    for name in ("serve.bundle.queue_wait", "serve.bundle.compile",
-                 "serve.bundle.execute"):
+    for name in ("serve.bundle.queue_wait", "serve.execute",
+                 "serve.resolve"):
         got = len(spans.get(name, ()))
         assert got == bundles, \
             f"{name}: {got} spans for {bundles} flushed bundles"
+    assert len(spans.get("serve.compile", ())) == srv.cache.misses
     fd, path = tempfile.mkstemp(suffix=".json")
     os.close(fd)
     try:
@@ -240,7 +242,8 @@ def _profiled_trace_smoke(nreq: int = 96, verbose: bool = True) -> None:
         assert all(e["ph"] == "X" and e["dur"] >= 0 and e["ts"] >= 0
                    for e in ev)
         per_bundle = [e for e in ev
-                      if e["name"].startswith("serve.bundle.")]
+                      if e["name"] in ("serve.bundle.queue_wait",
+                                       "serve.execute", "serve.resolve")]
         assert len(per_bundle) == 3 * bundles
     finally:
         os.unlink(path)

@@ -108,6 +108,17 @@ class EnsembleStats(NamedTuple):
     retcodes: Optional[jnp.ndarray] = None  # (nsys,) int32 CV_*-style flag
     # per system (repro.core.status; 0 == SUCCESS, negative == quarantined)
     ok: Optional[jnp.ndarray] = None        # (nsys,) bool, retcodes == 0
+    # loop trips of the whole batch (ensemble BDF), broadcast like nli:
+    # step-loop iterations, Newton-loop iterations over all steps, and
+    # steps whose lsetup branch ran.  The kernels work over every lane
+    # on each trip, so sum(attempts) / (trips * nsys) is the step loop's
+    # lane occupancy and sum(nni) / (newton_trips * nsys) the Newton's.
+    # On the sharded path each entry holds its own shard's trips (each
+    # device runs its own loops): occupancy there sums over the shard's
+    # lanes and divides by the shard's lane count
+    trips: Optional[jnp.ndarray] = None
+    newton_trips: Optional[jnp.ndarray] = None
+    setup_trips: Optional[jnp.ndarray] = None
 
     def masked(self, live) -> "EnsembleStats":
         """Stats restricted to the ``live`` lanes of a padded bundle.
@@ -118,8 +129,10 @@ class EnsembleStats(NamedTuple):
         counters are zeroed and it reports success (sums and means over
         the batch then describe live systems only).  The solver-level
         broadcast counters (``nli``, ``npsolves``) are GLOBAL totals of
-        the batched inner solves — they are not per-lane attributable
-        and pass through unchanged.
+        the batched inner solves, and the loop trips (``trips``,
+        ``newton_trips``, ``setup_trips``) count the batch's loops —
+        none is per-lane attributable, and they pass through
+        unchanged.
         """
         live = jnp.asarray(live, bool)
 
@@ -510,6 +523,9 @@ class _BdfCarry(NamedTuple):
     #                           on the CURRENT step (reset on accept)
     nef_cur: jnp.ndarray      # (nsys,) consecutive error-test failures
     #                           on the CURRENT step (reset on accept)
+    trips: jnp.ndarray        # scalar: step-loop iterations
+    newton_trips: jnp.ndarray  # scalar: Newton-loop iterations, all steps
+    setup_trips: jnp.ndarray  # scalar: steps whose lsetup branch ran
 
 
 def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
@@ -650,6 +666,13 @@ def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
     recorded value is an intermediate the step already computes, so with
     ``telemetry=None`` (the default) the loop trace is *identical* to a
     build without this feature (sunlint ``telemetry-purity``).
+
+    **Phases and loop trips.**  Each phase of the step runs under a
+    ``jax.named_scope`` (``ensemble_bdf.rescale``, ``.predict``,
+    ``.lsetup``, ``.newton``, ``.error_test``, ``.update``) that names
+    its compiled ops in a device trace, and ``stats.trips``,
+    ``stats.newton_trips`` and ``stats.setup_trips`` count the batch's
+    step-loop, Newton-loop and lsetup-branch iterations.
     """
     from .linsol import BlockDiagGJ
 
@@ -701,70 +724,83 @@ def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
         # max_steps attempts quarantines itself with TOO_MUCH_WORK and
         # drops out of the retcode mask — but it keeps an explicit
         # iteration ceiling in the cond (sunlint bounded-loops)
-        return jnp.any((c.t < tf * (1 - 1e-12)) & (c.retcode == 0)) & \
-            jnp.all(c.att <= opts.max_steps)
+        with jax.named_scope("ensemble_bdf.update"):
+            return jnp.any((c.t < tf * (1 - 1e-12)) & (c.retcode == 0)) & \
+                jnp.all(c.att <= opts.max_steps)
 
     def step(c):
-        active = (c.t < tf * (1 - 1e-12)) & (c.retcode == 0)
-        hs = jnp.where(active, jnp.minimum(c.h, tf - c.t), c.h)
-        nvalid = jnp.minimum(c.steps, QMAX)
-        # if h was clipped to hit tf, rescale the history accordingly
-        # (fused masked rebuild).  Unclipped systems have eta_clip ==
-        # 1.0 exactly (hs == c.h -> hs/c.h == 1.0) and _lagrange_matrix
-        # at eta=1 is the exact identity, so masking them out is a
-        # value-level no-op that lets the kernel short-circuit whole
-        # bundles in the common no-clip case instead of sweeping the
-        # full (QMAX+1, n, nsys) window every step
-        eta_clip = jnp.where(active, hs / c.h, one)
-        W = jax.vmap(_cv._lagrange_matrix)(eta_clip, nvalid)
-        Z = dv.history_rescale_soa(jnp.transpose(W, (1, 2, 0)), c.Z,
-                                   active & (eta_clip != one), policy)
-        qi = c.q - 1
-        alphas = jnp.asarray(_cv._ALPHA_T, dtype)[qi]   # (nsys, QMAX+1)
-        beta = jnp.asarray(_cv._BETA_T, dtype)[qi]      # (nsys,)
-        p_pred = jnp.minimum(nvalid, c.q)
-        pred_c = jnp.asarray(_cv._PREDP_T, dtype)[p_pred]
-        # predictor / psi: per-system coefficient contractions over the
-        # history, evaluated as the AoS einsum on transposed views so
-        # the jnp backend keeps the pre-SoA accumulation order bitwise
-        # (XLA folds the layout changes into the contraction).  O(Q*n*
-        # nsys) once per step — NOT per Newton iteration.  HIGHEST keeps
-        # a TPU from rounding the operands to bfloat16.
-        Zaos = jnp.transpose(Z, (2, 0, 1))           # (nsys, QMAX+1, n)
-        y_pred = jnp.einsum("sj,sjk->sk", pred_c, Zaos,
-                            precision=lax.Precision.HIGHEST).T  # (n, nsys)
-        psi = (-jnp.einsum("sj,sjk->sk", alphas[:, 1:], Zaos[:, :-1],
-                           precision=lax.Precision.HIGHEST)).T
-        gamma = beta * hs                            # (nsys,)
-        t_new = c.t + hs
-        w = 1.0 / (opts.rtol * jnp.abs(Z[0]) + opts.atol)   # (n, nsys)
+        # each phase of the step runs under a named scope: the compiled
+        # ops carry it in their op_name metadata (device-trace names
+        # only; the arithmetic is unchanged)
+        with jax.named_scope("ensemble_bdf.predict"):
+            active = (c.t < tf * (1 - 1e-12)) & (c.retcode == 0)
+            hs = jnp.where(active, jnp.minimum(c.h, tf - c.t), c.h)
+            nvalid = jnp.minimum(c.steps, QMAX)
+        with jax.named_scope("ensemble_bdf.rescale"):
+            # if h was clipped to hit tf, rescale the history accordingly
+            # (fused masked rebuild).  Unclipped systems have eta_clip ==
+            # 1.0 exactly (hs == c.h -> hs/c.h == 1.0) and
+            # _lagrange_matrix at eta=1 is the exact identity, so masking
+            # them out is a value-level no-op that lets the kernel
+            # short-circuit whole bundles in the common no-clip case
+            # instead of sweeping the full (QMAX+1, n, nsys) window
+            # every step
+            eta_clip = jnp.where(active, hs / c.h, one)
+            W = jax.vmap(_cv._lagrange_matrix)(eta_clip, nvalid)
+            Z = dv.history_rescale_soa(jnp.transpose(W, (1, 2, 0)), c.Z,
+                                       active & (eta_clip != one), policy)
+        with jax.named_scope("ensemble_bdf.predict"):
+            qi = c.q - 1
+            alphas = jnp.asarray(_cv._ALPHA_T, dtype)[qi]  # (nsys, QMAX+1)
+            beta = jnp.asarray(_cv._BETA_T, dtype)[qi]     # (nsys,)
+            p_pred = jnp.minimum(nvalid, c.q)
+            pred_c = jnp.asarray(_cv._PREDP_T, dtype)[p_pred]
+            # predictor / psi: per-system coefficient contractions over
+            # the history, evaluated as the AoS einsum on transposed
+            # views so the jnp backend keeps the pre-SoA accumulation
+            # order bitwise (XLA folds the layout changes into the
+            # contraction).  O(Q*n*nsys) once per step — NOT per Newton
+            # iteration.  HIGHEST keeps a TPU from rounding the operands
+            # to bfloat16.
+            Zaos = jnp.transpose(Z, (2, 0, 1))       # (nsys, QMAX+1, n)
+            y_pred = jnp.einsum("sj,sjk->sk", pred_c, Zaos,
+                                precision=lax.Precision.HIGHEST).T
+            psi = (-jnp.einsum("sj,sjk->sk", alphas[:, 1:], Zaos[:, :-1],
+                               precision=lax.Precision.HIGHEST)).T
+            gamma = beta * hs                        # (nsys,)
+            t_new = c.t + hs
+            w = 1.0 / (opts.rtol * jnp.abs(Z[0]) + opts.atol)  # (n, nsys)
 
         # ---- lsetup: refresh J (and in 'setup' mode the block inverse)
         # only where stale; skipped entirely when no system needs it.
         # NOTE the batch-granular cost: one system tripping a trigger
         # evaluates jac over ALL nsys systems (docstring lsetup note) --
-        gamrat = gamma / jnp.where(c.gam_saved != 0, c.gam_saved, gamma)
-        need = active & ((c.gam_saved == 0) | c.ncf_prev |
-                         (c.since_jac >= msbp) |
-                         (jnp.abs(gamrat - 1.0) > dgmax))
+        with jax.named_scope("ensemble_bdf.lsetup"):
+            gamrat = gamma / jnp.where(c.gam_saved != 0, c.gam_saved, gamma)
+            need = active & ((c.gam_saved == 0) | c.ncf_prev |
+                             (c.since_jac >= msbp) |
+                             (jnp.abs(gamrat - 1.0) > dgmax))
+            any_need = jnp.any(need)
 
-        def do_setup(_):
-            return ls.soa_setup(jac_s(t_new, y_pred), gamma, policy)
+            def do_setup(_):
+                return ls.soa_setup(jac_s(t_new, y_pred), gamma, policy)
 
-        MJ_new = lax.cond(jnp.any(need), do_setup, lambda _: c.MJ,
-                          operand=None)
-        # solver-defined pytree; every leaf keeps nsys LAST, so the
-        # per-system mask broadcasts against the trailing axis.  When
-        # EVERY system needs the refresh (cold start, the common case)
-        # the fresh object is taken wholesale — no MJ-sized select.
-        MJ = lax.cond(
-            jnp.all(need),
-            lambda: MJ_new,
-            lambda: jax.tree_util.tree_map(
-                lambda new, old: jnp.where(need, new, old), MJ_new, c.MJ))
-        gam_saved = jnp.where(need, gamma, c.gam_saved)
-        since_jac = jnp.where(need, 0, c.since_jac)
-        gamrat = jnp.where(need, 1.0, gamrat)
+            MJ_new = lax.cond(any_need, do_setup, lambda _: c.MJ,
+                              operand=None)
+            # solver-defined pytree; every leaf keeps nsys LAST, so the
+            # per-system mask broadcasts against the trailing axis.  When
+            # EVERY system needs the refresh (cold start, the common
+            # case) the fresh object is taken wholesale — no MJ-sized
+            # select.
+            MJ = lax.cond(
+                jnp.all(need),
+                lambda: MJ_new,
+                lambda: jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(need, new, old), MJ_new,
+                    c.MJ))
+            gam_saved = jnp.where(need, gamma, c.gam_saved)
+            since_jac = jnp.where(need, 0, c.since_jac)
+            gamrat = jnp.where(need, 1.0, gamrat)
 
         # ---- convergence-tested modified Newton, all-SoA: residual,
         # lsolve, masked update and correction norm each one fused op
@@ -798,92 +834,108 @@ def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
                     conv_new, div_new, nni_s + iterate.astype(jnp.int32),
                     nli_s + nli_inc, nps_s + nps_inc)
 
-        s0 = (y_pred, jnp.zeros((), jnp.int32), jnp.zeros((nsys,), dtype),
-              jnp.ones((nsys,), dtype), ~active, jnp.zeros((nsys,), bool),
-              jnp.zeros((nsys,), jnp.int32), jnp.zeros((), jnp.int32),
-              jnp.zeros((), jnp.int32))
-        z, _, _, _, conv, _, nni_s, nli_s, nps_s = lax.while_loop(
-            nl_cond, nl_body, s0)
+        with jax.named_scope("ensemble_bdf.newton"):
+            s0 = (y_pred, jnp.zeros((), jnp.int32),
+                  jnp.zeros((nsys,), dtype), jnp.ones((nsys,), dtype),
+                  ~active, jnp.zeros((nsys,), bool),
+                  jnp.zeros((nsys,), jnp.int32), jnp.zeros((), jnp.int32),
+                  jnp.zeros((), jnp.int32))
+            # the final iteration count is the loop's trip count: the
+            # loop runs while any lane iterates
+            z, newton_trips, _, _, conv, _, nni_s, nli_s, nps_s = \
+                lax.while_loop(nl_cond, nl_body, s0)
 
         # ---- local error test (LTE ~ (z - pred)/(q+1), uniform grid) ----
-        err_raw = dv.wrms_soa(z - y_pred, w, policy) / \
-            (c.q.astype(dtype) + 1.0)
-        bad = ~jnp.isfinite(err_raw) | ~conv
-        err = jnp.where(bad, 2.0, err_raw)
-        accept = (err <= 1.0) & ~bad & active
+        with jax.named_scope("ensemble_bdf.error_test"):
+            err_raw = dv.wrms_soa(z - y_pred, w, policy) / \
+                (c.q.astype(dtype) + 1.0)
+            bad = ~jnp.isfinite(err_raw) | ~conv
+            err = jnp.where(bad, 2.0, err_raw)
+            accept = (err <= 1.0) & ~bad & active
 
-        cst = ctrl.ControllerState(err_prev=c.e1, err_prev2=c.e2)
-        eta, cst_new = ctrl.eta_from_error(opts.controller, cst, err,
-                                           c.q + 1,
-                                           after_failure=(~accept) & conv)
-        eta = jnp.where(conv | ~active, eta, opts.eta_cf)
-        eta = jnp.clip(eta, 0.1, 10.0)
-        # fold the [hmin, hmax] step bounds into eta itself: the history
-        # below is rescaled onto the hs*eta grid, so clamping h after the
-        # fact would leave the stored grid and the carried h disagreeing
-        # whenever the bound engages
-        hs_safe = jnp.maximum(hs, jnp.finfo(dtype).tiny)
-        eta = jnp.clip(eta, opts.hmin / hs_safe, opts.hmax / hs_safe)
-        e1 = jnp.where(accept, cst_new.err_prev, c.e1)
-        e2 = jnp.where(accept, cst_new.err_prev2, c.e2)
+            cst = ctrl.ControllerState(err_prev=c.e1, err_prev2=c.e2)
+            eta, cst_new = ctrl.eta_from_error(opts.controller, cst, err,
+                                               c.q + 1,
+                                               after_failure=(~accept) & conv)
+            eta = jnp.where(conv | ~active, eta, opts.eta_cf)
+            eta = jnp.clip(eta, 0.1, 10.0)
+            # fold the [hmin, hmax] step bounds into eta itself: the
+            # history below is rescaled onto the hs*eta grid, so clamping
+            # h after the fact would leave the stored grid and the
+            # carried h disagreeing whenever the bound engages
+            hs_safe = jnp.maximum(hs, jnp.finfo(dtype).tiny)
+            eta = jnp.clip(eta, opts.hmin / hs_safe, opts.hmax / hs_safe)
+            e1 = jnp.where(accept, cst_new.err_prev, c.e1)
+            e2 = jnp.where(accept, cst_new.err_prev2, c.e2)
 
         # accepted systems: shift history, insert z, ramp order
-        Z_acc = jnp.roll(Z, 1, axis=0).at[0].set(z)
-        Z_next = jnp.where(accept[None, None, :], Z_acc, Z)
-        q_next = jnp.where(accept, jnp.minimum(c.q + 1, order), c.q)
+        with jax.named_scope("ensemble_bdf.update"):
+            Z_acc = jnp.roll(Z, 1, axis=0).at[0].set(z)
+            Z_next = jnp.where(accept[None, None, :], Z_acc, Z)
+            q_next = jnp.where(accept, jnp.minimum(c.q + 1, order), c.q)
         # rescale each system's history onto its new uniform grid
-        nval_after = jnp.minimum(c.steps + accept.astype(jnp.int32), QMAX)
-        W2 = jax.vmap(_cv._lagrange_matrix)(
-            jnp.where(active, eta, one), nval_after)
-        Z_next = dv.history_rescale_soa(jnp.transpose(W2, (1, 2, 0)),
-                                        Z_next, active, policy)
+        with jax.named_scope("ensemble_bdf.rescale"):
+            nval_after = jnp.minimum(c.steps + accept.astype(jnp.int32),
+                                     QMAX)
+            W2 = jax.vmap(_cv._lagrange_matrix)(
+                jnp.where(active, eta, one), nval_after)
+            Z_next = dv.history_rescale_soa(jnp.transpose(W2, (1, 2, 0)),
+                                            Z_next, active, policy)
 
-        t_next = jnp.where(accept, t_new, c.t)
-        h_next = jnp.where(active, hs * eta, c.h)
-        ncf = active & ~conv
-        etf = (~accept) & conv & active
-        ai = active.astype(jnp.int32)
-        att_next = c.att + ai
+        with jax.named_scope("ensemble_bdf.update"):
+            t_next = jnp.where(accept, t_new, c.t)
+            h_next = jnp.where(active, hs * eta, c.h)
+            ncf = active & ~conv
+            etf = (~accept) & conv & active
+            ai = active.astype(jnp.int32)
+            att_next = c.att + ai
 
-        # ---- per-lane retcode escalation (CVODE CVHandleFailure
-        # semantics, carried in data).  Failure is only ever DECIDED for
-        # currently-active lanes, so a quarantined lane's retcode is
-        # sticky and healthy lanes see pure where() no-ops — the
-        # no-fault trace stays value-identical.  Priority (last write
-        # wins): TOO_MUCH_WORK < ERR_FAILURE < CONV_FAILURE <
-        # RHSFUNC_FAIL, mirroring CVODE's specific-beats-generic flags.
-        ncf_cur = jnp.where(accept, 0, c.ncf_cur + ncf.astype(jnp.int32))
-        nef_cur = jnp.where(accept, 0, c.nef_cur + etf.astype(jnp.int32))
-        # step-size underflow is RELATIVE (t + h == t, the classic
-        # "h below the ULP of t" check): stiff lanes legitimately visit
-        # tiny absolute h near transients and recover, so an absolute
-        # floor would quarantine healthy integrations
-        hfail = active & (c.t + hs * eta == c.t)
-        nanstep = active & conv & ~jnp.isfinite(err_raw)
-        unfinished = t_next < tf * (1 - 1e-12)
-        rc = c.retcode
-        rc = jnp.where(active & unfinished & (att_next >= opts.max_steps),
-                       status.TOO_MUCH_WORK, rc)
-        rc = jnp.where(active & ((nef_cur >= status.MXNEF) |
-                                 (hfail & conv)),
-                       status.ERR_FAILURE, rc)
-        rc = jnp.where(active & ((ncf_cur >= status.MXNCF) |
-                                 (hfail & ~conv)),
-                       status.CONV_FAILURE, rc)
-        rc = jnp.where(nanstep, status.RHSFUNC_FAIL, rc)
+            # ---- per-lane retcode escalation (CVODE CVHandleFailure
+            # semantics, carried in data).  Failure is only ever DECIDED
+            # for currently-active lanes, so a quarantined lane's retcode
+            # is sticky and healthy lanes see pure where() no-ops — the
+            # no-fault trace stays value-identical.  Priority (last write
+            # wins): TOO_MUCH_WORK < ERR_FAILURE < CONV_FAILURE <
+            # RHSFUNC_FAIL, mirroring CVODE's specific-beats-generic
+            # flags.
+            ncf_cur = jnp.where(accept, 0,
+                                c.ncf_cur + ncf.astype(jnp.int32))
+            nef_cur = jnp.where(accept, 0,
+                                c.nef_cur + etf.astype(jnp.int32))
+            # step-size underflow is RELATIVE (t + h == t, the classic
+            # "h below the ULP of t" check): stiff lanes legitimately
+            # visit tiny absolute h near transients and recover, so an
+            # absolute floor would quarantine healthy integrations
+            hfail = active & (c.t + hs * eta == c.t)
+            nanstep = active & conv & ~jnp.isfinite(err_raw)
+            unfinished = t_next < tf * (1 - 1e-12)
+            rc = c.retcode
+            rc = jnp.where(active & unfinished &
+                           (att_next >= opts.max_steps),
+                           status.TOO_MUCH_WORK, rc)
+            rc = jnp.where(active & ((nef_cur >= status.MXNEF) |
+                                     (hfail & conv)),
+                           status.ERR_FAILURE, rc)
+            rc = jnp.where(active & ((ncf_cur >= status.MXNCF) |
+                                     (hfail & ~conv)),
+                           status.CONV_FAILURE, rc)
+            rc = jnp.where(nanstep, status.RHSFUNC_FAIL, rc)
 
-        carry = _BdfCarry(
-            t=t_next, h=h_next, q=q_next, Z=Z_next, e1=e1, e2=e2,
-            MJ=MJ, gam_saved=gam_saved, since_jac=since_jac + ai,
-            ncf_prev=ncf,
-            steps=c.steps + accept.astype(jnp.int32),
-            att=att_next,
-            netf=c.netf + etf.astype(jnp.int32),
-            nni=c.nni + nni_s,
-            nsetups=c.nsetups + need.astype(jnp.int32),
-            ncfn=c.ncfn + ncf.astype(jnp.int32),
-            nli=c.nli + nli_s, nps=c.nps + nps_s,
-            retcode=rc, ncf_cur=ncf_cur, nef_cur=nef_cur)
+            carry = _BdfCarry(
+                t=t_next, h=h_next, q=q_next, Z=Z_next, e1=e1, e2=e2,
+                MJ=MJ, gam_saved=gam_saved, since_jac=since_jac + ai,
+                ncf_prev=ncf,
+                steps=c.steps + accept.astype(jnp.int32),
+                att=att_next,
+                netf=c.netf + etf.astype(jnp.int32),
+                nni=c.nni + nni_s,
+                nsetups=c.nsetups + need.astype(jnp.int32),
+                ncfn=c.ncfn + ncf.astype(jnp.int32),
+                nli=c.nli + nli_s, nps=c.nps + nps_s,
+                retcode=rc, ncf_cur=ncf_cur, nef_cur=nef_cur,
+                trips=c.trips + 1,
+                newton_trips=c.newton_trips + newton_trips,
+                setup_trips=c.setup_trips + any_need.astype(jnp.int32))
         # telemetry record: every element is an intermediate the step
         # computed anyway — with telemetry off the tuple is discarded
         # and the traced loop is identical to a build without it
@@ -930,7 +982,10 @@ def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
         ncf_prev=jnp.zeros((nsys,), bool), steps=steps_init, att=zero(),
         netf=zero(), nni=zero(), nsetups=zero(), ncfn=zero(),
         nli=jnp.zeros((), jnp.int32), nps=jnp.zeros((), jnp.int32),
-        retcode=zero(), ncf_cur=zero(), nef_cur=zero())
+        retcode=zero(), ncf_cur=zero(), nef_cur=zero(),
+        trips=jnp.zeros((), jnp.int32),
+        newton_trips=jnp.zeros((), jnp.int32),
+        setup_trips=jnp.zeros((), jnp.int32))
     # every carry leaf is freshly allocated above -> donate, so the
     # history window is updated in place across the step loop
     ring = None
@@ -957,7 +1012,10 @@ def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
         success=c.t >= tf * (1 - 1e-10), nsetups=c.nsetups, ncfn=c.ncfn,
         nli=jnp.broadcast_to(c.nli, (nsys,)),
         npsolves=jnp.broadcast_to(c.nps, (nsys,)),
-        retcodes=retcodes, ok=retcodes == 0)
+        retcodes=retcodes, ok=retcodes == 0,
+        trips=jnp.broadcast_to(c.trips, (nsys,)),
+        newton_trips=jnp.broadcast_to(c.newton_trips, (nsys,)),
+        setup_trips=jnp.broadcast_to(c.setup_trips, (nsys,)))
     out = [c.Z[0].T, st]
     if return_session:
         # built from the loop OUTPUTS — fresh buffers, never the
@@ -1066,6 +1124,8 @@ def ensemble_bdf_integrate_sharded(f: Callable, jac: Callable,
         shard = y0.shape[0] // ndev
         st = st._replace(nli=jnp.broadcast_to(jnp.sum(st.nli[::shard]),
                                               st.nli.shape))
+    # trips, newton_trips and setup_trips stay per shard: each device
+    # runs its own loops
     if st.npsolves is not None:
         shard = y0.shape[0] // ndev
         st = st._replace(npsolves=jnp.broadcast_to(

@@ -5,11 +5,8 @@ Everything is OFF by default, and the disabled path is contractually
 free: with the default config, ``integrate`` takes exactly the code
 path it took before this subsystem existed, so the jitted hot-loop
 jaxprs are *identical* to a no-observability build (statically checked
-by sunlint's ``telemetry-purity`` rule) and ``benchmarks/
-observability_bench.py`` gates the wall-clock ratio at <= 1.02.  The
-enabled path buys step telemetry + region profiling for <= 5% on the
-BENCH_ensemble configs — the paper's "negligible overhead" thesis,
-applied to our own instrumentation.
+by sunlint's ``telemetry-purity`` rule).  What the enabled path costs
+on a TPU is measured in PERF.md.
 """
 from __future__ import annotations
 

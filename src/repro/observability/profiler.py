@@ -9,7 +9,9 @@ JAX stack:
 * ``with prof.region("integrate.execute"):`` — nestable context-manager
   regions; exit optionally blocks on an enqueued device token
   (``sync=True``) so dispatched-but-unfinished XLA work lands inside
-  the region that dispatched it.
+  the region that dispatched it.  Every region also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so it appears on the
+  host plane of a JAX profiler trace, on the device ops' clock.
 * ``prof.add_span(name, t0, t1)`` — raw span injection for events timed
   on a foreign clock (the serving queue's arrival/flush timestamps are
   mapped into the profiler timebase and recorded per bundle).
@@ -19,8 +21,9 @@ JAX stack:
   merged host-region + serving-queue timeline as Chrome-trace JSON
   (load in ``chrome://tracing`` or https://ui.perfetto.dev).
 
-A disabled profiler hands out one shared no-op region object and
-records nothing — the off cost is a single attribute check.
+A disabled profiler records nothing; its regions are the bare
+annotations, which record only while a JAX profiler trace is being
+taken.
 """
 from __future__ import annotations
 
@@ -29,6 +32,8 @@ import threading
 import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclass(frozen=True)
@@ -48,21 +53,6 @@ class Span:
         return self.t1 - self.t0
 
 
-class _NullRegion:
-    """The disabled-profiler region: a shared, stateless no-op."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_REGION = _NullRegion()
-
-
 def _device_sync() -> None:
     """Block until previously-enqueued device work has retired, by
     enqueueing a trivial op and waiting on it (the portable analog of
@@ -79,7 +69,7 @@ class _Region:
     """An active region; created per ``with`` entry (regions nest)."""
 
     __slots__ = ("_prof", "name", "cat", "sync", "args", "_t0", "_depth",
-                 "_tid")
+                 "_tid", "_ann")
 
     def __init__(self, prof: "Profiler", name: str, cat: str, sync: bool,
                  args: Optional[dict]):
@@ -94,6 +84,8 @@ class _Region:
         self._depth = getattr(tl, "depth", 0)
         tl.depth = self._depth + 1
         self._tid = threading.get_ident()
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = self._prof.clock()
         return self
 
@@ -101,6 +93,7 @@ class _Region:
         if self.sync:
             self._prof._sync_fn()
         t1 = self._prof.clock()
+        self._ann.__exit__(*exc)
         self._prof._tls.depth = self._depth
         self._prof.add_span(self.name, self._t0, t1, cat=self.cat,
                             args=self.args, tid=self._tid,
@@ -131,9 +124,10 @@ class Profiler:
 
     def region(self, name: str, cat: str = "host",
                sync: Optional[bool] = None, **args):
-        """A nestable timed region; no-op when disabled."""
+        """A nestable timed region; when disabled, only the annotation
+        of the JAX profiler trace."""
         if not self.enabled:
-            return _NULL_REGION
+            return TraceAnnotation(name)
         return _Region(self, name, cat,
                        self.sync if sync is None else bool(sync),
                        args or None)

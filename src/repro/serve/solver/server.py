@@ -561,20 +561,22 @@ class SolverServer:
             if not hit and self._bundles >= self.warmup_bundles:
                 with self._mlock:
                     self._steady_misses += 1
-            t0 = time.perf_counter()
-            try:
-                y, st, sess_out = self._run_compiled(entry, sess, tfa,
-                                                     params)
-                if self._needs_fallback(y):
-                    raise RuntimeError(
-                        "bundle state is entirely non-finite under "
-                        f"backend {self.ctx.policy.backend!r}")
-            except Exception as fallback_exc:
-                y, st, sess_out, entry, hit = self._degrade(
-                    bundle, sess, tfa, params, fallback_exc)
-                degraded = True
-            t1 = time.perf_counter()
-            exec_s = t1 - t0
+            with prof.region("serve.execute", cat="serve", sync=False,
+                             family=bundle.key.family, live=bundle.live,
+                             nsys=bundle.nsys):
+                t0 = time.perf_counter()
+                try:
+                    y, st, sess_out = self._run_compiled(entry, sess, tfa,
+                                                         params)
+                    if self._needs_fallback(y):
+                        raise RuntimeError(
+                            "bundle state is entirely non-finite under "
+                            f"backend {self.ctx.policy.backend!r}")
+                except Exception as fallback_exc:
+                    y, st, sess_out, entry, hit = self._degrade(
+                        bundle, sess, tfa, params, fallback_exc)
+                    degraded = True
+                exec_s = time.perf_counter() - t0
         except Exception as exc:       # resolve, don't strand, futures
             self._count_failures("exec_error", len(bundle.requests))
             for req in bundle.requests:
@@ -599,21 +601,15 @@ class SolverServer:
             row["bundles"] += 1
             row["exec_s"] += exec_s
         if prof.enabled:
-            # per-bundle serving timeline (arrival -> flush -> compile
-            # -> execute), mapped onto the profiler timebase; profiler
-            # clock defaults to perf_counter, the execute stamps' base
+            # the bundle's queue wait (arrival -> flush) was stamped on
+            # the server clock before this call: mapped onto the
+            # profiler timebase and recorded after the fact
             pmap = lambda ts: p_anchor + (ts - s_anchor)
-            wait0 = pmap(min(r.arrival for r in bundle.requests))
-            flush = pmap(bundle.flushed)
-            args = {"family": bundle.key.family, "live": bundle.live,
-                    "nsys": bundle.nsys}
-            prof.add_span("serve.bundle.queue_wait", wait0, flush,
-                          cat="serve", args=args)
-            prof.add_span("serve.bundle.compile", flush,
-                          flush + (0.0 if hit else entry.compile_s),
-                          cat="serve", args={**args, "cached": hit})
-            prof.add_span("serve.bundle.execute", t0, t1,
-                          cat="serve", args=args)
+            prof.add_span("serve.bundle.queue_wait",
+                          pmap(min(r.arrival for r in bundle.requests)),
+                          pmap(bundle.flushed), cat="serve",
+                          args={"family": bundle.key.family,
+                                "live": bundle.live, "nsys": bundle.nsys})
         log = self.ctx.logger
         if log.enabled_for("INFO"):
             log.info("serve.bundle", family=bundle.key.family,
@@ -623,30 +619,31 @@ class SolverServer:
         # per-lane retcode inspection: only OFFENDING lanes fail (typed
         # SolverError with retcode + per-lane stats); bundle-mates
         # resolve normally — the serving face of quarantine containment
-        retcodes = None
-        if getattr(st, "retcodes", None) is not None:
-            import numpy as np
+        with prof.region("serve.resolve", cat="serve", sync=False):
+            retcodes = None
+            if getattr(st, "retcodes", None) is not None:
+                import numpy as np
 
-            retcodes = np.asarray(st.retcodes)
-        failed_lanes = []
-        for i, req in enumerate(bundle.requests):
-            rc = int(retcodes[i]) if retcodes is not None else 0
-            if rc != 0:
-                lane_stats = jax.tree_util.tree_map(
-                    lambda a: a[..., i], st)
-                exc = SolverError(
-                    f"lane failed with {_status.retcode_name(rc)} "
-                    f"({rc}) [{_status.SUNDIALS_FLAGS.get(rc, '?')}]",
-                    retcode=rc, stats=lane_stats)
-                self._count_failures(_status.retcode_name(rc))
-                failed_lanes.append(i)
+                retcodes = np.asarray(st.retcodes)
+            failed_lanes = []
+            for i, req in enumerate(bundle.requests):
+                rc = int(retcodes[i]) if retcodes is not None else 0
+                if rc != 0:
+                    lane_stats = jax.tree_util.tree_map(
+                        lambda a: a[..., i], st)
+                    exc = SolverError(
+                        f"lane failed with {_status.retcode_name(rc)} "
+                        f"({rc}) [{_status.SUNDIALS_FLAGS.get(rc, '?')}]",
+                        retcode=rc, stats=lane_stats)
+                    self._count_failures(_status.retcode_name(rc))
+                    failed_lanes.append(i)
+                    if req.future.set_running_or_notify_cancel():
+                        req.future.set_exception(exc)
+                    continue
+                sol = self._lane_solution(i, req, bundle, y, st, sess_out,
+                                          entry, hit, exec_s, degraded)
                 if req.future.set_running_or_notify_cancel():
-                    req.future.set_exception(exc)
-                continue
-            sol = self._lane_solution(i, req, bundle, y, st, sess_out,
-                                      entry, hit, exec_s, degraded)
-            if req.future.set_running_or_notify_cancel():
-                req.future.set_result(sol)
+                    req.future.set_result(sol)
         if failed_lanes and log.enabled_for("WARNING"):
             log.warning("serve.lane_failed", family=bundle.key.family,
                         failed=len(failed_lanes), live=bundle.live,

@@ -4,12 +4,11 @@ span surface.
 
 The load-bearing assertions are the *exact* reconciliations: recorded
 ring-buffer telemetry must sum to the very counters the Solution
-reports (steps, Newton iterations, lsetups) — per system, including
-padded dead lanes and the warm-start continuation leg.  The structural
-zero-overhead contract (disabled config leaves the hot-loop jaxpr
-byte-identical) is checked statically by the ``telemetry-purity``
-sunlint rule; the runtime ceilings live in
-``benchmarks/observability_bench.py``.
+reports (steps, Newton iterations, lsetups, and the batch's loop trips)
+— per system, including padded dead lanes and the warm-start
+continuation leg.  The structural zero-overhead contract (disabled
+config leaves the hot-loop jaxpr byte-identical) is checked statically
+by the ``telemetry-purity`` sunlint rule.
 """
 import json
 
@@ -54,11 +53,12 @@ class TestConfig:
 
 class TestProfiler:
     def test_disabled_is_a_shared_noop(self):
+        """A disabled profiler stores no span, though its regions (nested,
+        synced) are entered and a span is added."""
         p = Profiler(enabled=False)
-        r1, r2 = p.region("a"), p.region("b")
-        assert r1 is r2                      # one shared null region
-        with r1:
-            pass
+        with p.region("a"):
+            with p.region("b", sync=True):
+                pass
         p.add_span("x", 0.0, 1.0)
         assert p.spans == []
 
@@ -87,6 +87,31 @@ class TestProfiler:
         with p.region("nosync", sync=False):
             pass
         assert calls == [1]
+
+    def test_regions_reach_the_jax_profiler_trace(self, tmp_path):
+        """A region, enabled or not, is a host span of the JAX profiler's
+        trace: on the device ops' clock, in the same file."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        on, off = Profiler(enabled=True, sync=False), Profiler(enabled=False)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with on.region("serve.execute"):
+                with off.region("serve.resolve"):
+                    pass
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        names = {e.name for p in ProfileData.from_file(path).planes
+                 if p.name.startswith("/host:")
+                 for line in p.lines for e in line.events}
+        assert {"serve.execute", "serve.resolve"} <= names
+        assert [s.name for s in on.spans] == ["serve.execute"]
+        assert off.spans == []
 
     def test_chrome_trace_export(self, tmp_path):
         p = Profiler(enabled=True, sync=False)
@@ -358,6 +383,67 @@ class TestIntegrateTelemetry:
             np.asarray(leg2.stats.nni).tolist()
 
 
+def _rob_family_prob(k3):
+    """Robertson ensemble with per-lane k3 (the standard k1, k2)."""
+    f, jac, f_soa, jac_soa = robertson_family()
+    nsys = k3.shape[0]
+    p = {"k1": jnp.full((nsys,), ROB_PARAMS["k1"]),
+         "k2": jnp.full((nsys,), ROB_PARAMS["k2"]), "k3": k3}
+    y0 = jnp.tile(jnp.asarray([1.0, 0.0, 0.0]), (nsys, 1))
+    return IVP(f=lambda t, y: f(t, y, p), jac=lambda t, y: jac(t, y, p),
+               f_soa=lambda t, z: f_soa(t, z, p),
+               jac_soa=lambda t, z: jac_soa(t, z, p), y0=y0)
+
+
+@pytest.fixture(scope="module")
+def spread_sol():
+    """256 lanes whose k3 spans four decades: their step and Newton
+    counts diverge, so the batch's loops run past most lanes."""
+    prob = _rob_family_prob(jnp.logspace(5.0, 9.0, 256))
+    return integrate(prob, 0.0, 1.0, "ensemble_bdf", telemetry=2048)
+
+
+class TestLoopTrips:
+    """The batch's loop trips against the per-lane step records."""
+
+    def test_trips_reconcile_with_telemetry(self, spread_sol):
+        st, tel = spread_sol.stats, spread_sol.telemetry
+        assert not tel.truncated
+        att = np.asarray(st.attempts)
+        assert att.min() < att.max()          # the lanes did diverge
+        for name in ("trips", "newton_trips", "setup_trips"):
+            v = np.asarray(getattr(st, name))
+            assert v.shape == (256,) and (v == v[0]).all(), name
+        # one record per step-loop trip; a Newton trip runs while any
+        # lane iterates; the lsetup branch runs when any lane needs it
+        assert int(st.trips[0]) == att.max() == tel.records
+        assert int(st.newton_trips[0]) == \
+            int(tel.newton_iters.max(axis=1).sum())
+        assert int(st.setup_trips[0]) == \
+            int(tel.lsetup_fired.any(axis=1).sum())
+        # lane occupancy of both loops is below one
+        nsys = att.size
+        assert att.sum() < int(st.trips[0]) * nsys
+        assert int(np.asarray(st.nni).sum()) < \
+            int(st.newton_trips[0]) * nsys
+
+    def test_identical_lanes_run_every_trip(self):
+        sol = integrate(_rob_family_prob(jnp.full((8,), 3e7)), 0.0, 1.0,
+                        "ensemble_bdf")
+        st = sol.stats
+        assert np.asarray(st.attempts).tolist() == \
+            np.asarray(st.trips).tolist()
+
+    def test_masked_passes_trips_through(self, spread_sol):
+        st = spread_sol.stats
+        live = np.arange(256) < 100
+        m = st.masked(live)
+        for name in ("trips", "newton_trips", "setup_trips"):
+            assert np.array_equal(np.asarray(getattr(m, name)),
+                                  np.asarray(getattr(st, name))), name
+        assert np.asarray(m.attempts)[100:].sum() == 0
+
+
 class TestTimedIntegrate:
     def test_direct_timings_reported(self):
         sol = integrate(_rob_prob(2), 0.0, 0.05, "ensemble_bdf",
@@ -426,12 +512,15 @@ class TestServerObservability:
         by_name = {}
         for s in spans:
             by_name.setdefault(s.name, []).append(s)
-        for name in ("serve.bundle.queue_wait", "serve.bundle.compile",
-                     "serve.bundle.execute"):
+        for name in ("serve.bundle.queue_wait", "serve.execute",
+                     "serve.resolve"):
             assert len(by_name[name]) == bundles, name
+        # compiled once per cache miss, in the compile itself
+        assert len(by_name["serve.compile"]) == srv.cache.misses >= 1
+        assert "serve.bundle.compile" not in by_name
         # queue wait must precede execute on the shared timebase
         qw = by_name["serve.bundle.queue_wait"][0]
-        ex = by_name["serve.bundle.execute"][0]
+        ex = by_name["serve.execute"][0]
         assert qw.t0 <= ex.t1
         trace = srv.ctx.profiler.chrome_trace()
         assert all(e["ph"] == "X" for e in trace["traceEvents"])

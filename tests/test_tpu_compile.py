@@ -13,6 +13,7 @@ named explicitly: code that asks ``jax.default_backend()`` still sees
 the CPU here.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -159,7 +160,7 @@ def test_csr_kernel_refuses_to_compile():
                      indices=(0, 1), interpret=False)
 
 
-def _ensemble_bdf_text(one_chip, x64, backend):
+def _ensemble_bdf_text(one_chip, x64, backend, nsys=NSYS):
     """Compiled chip program of the float32 Robertson ensemble-BDF run
     under ``backend``."""
     from repro.core import problems
@@ -170,8 +171,8 @@ def _ensemble_bdf_text(one_chip, x64, backend):
 
     policy = ExecPolicy(backend=backend, interpret=False, device="tpu_v5e")
     with jax.enable_x64(False):         # float32 rate constants
-        f, jac, _ = problems.batched_robertson(NSYS)
-        f_soa, jac_soa = problems.batched_robertson_soa(NSYS)
+        f, jac, _ = problems.batched_robertson(nsys)
+        f_soa, jac_soa = problems.batched_robertson_soa(nsys)
 
     def run(y0):
         y, st = ensemble_bdf_integrate(
@@ -180,7 +181,7 @@ def _ensemble_bdf_text(one_chip, x64, backend):
             linear_solver=BlockDiagGJ(), f_soa=f_soa, jac_soa=jac_soa)
         return y, st.retcodes
 
-    return _compile(one_chip, x64, run, (NSYS, 3))
+    return _compile(one_chip, x64, run, (nsys, 3))
 
 
 @X64
@@ -199,6 +200,102 @@ def test_ensemble_bdf_keeps_float32_on_chip(one_chip, backend):
     TPU's default matmul precision: with it, every lane of the jnp
     ensemble failed its Newton iteration on a v5e."""
     assert "bf16" not in _ensemble_bdf_text(one_chip, False, backend)
+
+
+PHASES = {f"ensemble_bdf.{p}" for p in ("rescale", "predict", "lsetup",
+                                         "newton", "error_test", "update")}
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_NOT_RUN = ("parameter", "get-tuple-element", "tuple", "bitcast",
+            "constant")
+# instructions XLA adds on its own, which carry no scope of the program
+_XLA_OWN = ("copy", "copy-start", "copy-done", "broadcast")
+
+
+def _computations(text):
+    """``{computation: [instruction lines]}`` of an HLO module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        s = line.strip()
+        if s.endswith("{") and " -> " in s:
+            cur = s.split()[1 if s.startswith("ENTRY") else 0].lstrip("%")
+            comps[cur] = []
+        elif s == "}":
+            cur = None
+        elif cur is not None and " = " in s:
+            comps[cur].append(s)
+    return comps
+
+
+def _reachable(comps, root):
+    """``root`` and every computation it calls, transitively."""
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo.extend(n for s in comps[c]
+                        for n in re.findall(r"%([\w.\-]+)", s)
+                        if n in comps)
+    return seen
+
+
+def _phase(line):
+    """The innermost ``ensemble_bdf.*`` scope of an instruction."""
+    m = _OP_NAME.search(line)
+    found = [c for c in (m.group(1) if m else "").split("/")
+             if c.startswith("ensemble_bdf.")]
+    return found[-1] if found else None
+
+
+def _opcode(line):
+    rhs = line.split(" = ", 1)[1]
+    m = re.match(r"(?:\(.*?\)|\S+)\s+([\w\-]+)\(", rhs)
+    return m.group(1) if m else ""
+
+
+def _loop_body(comps, scope):
+    """The body computation of the one ``while`` whose op_name ends in
+    ``scope + "while"``."""
+    bodies = [re.search(r"body=%([\w.\-]+)", s).group(1)
+              for lines in comps.values() for s in lines
+              if _opcode(s) == "while" and _OP_NAME.search(s)
+              and _OP_NAME.search(s).group(1).endswith(scope + "while")]
+    assert len(bodies) == 1, (scope, bodies)
+    return bodies[0]
+
+
+def test_ensemble_bdf_ops_carry_their_phase(one_chip):
+    """Every instruction the step loop runs names its phase of the step
+    in its op_name metadata, apart from the copies, prefetches and
+    broadcasts XLA adds on its own; every fusion of the Newton loop
+    names the Newton phase.  The device trace's ops are attributed to a
+    phase by that name."""
+    text = _ensemble_bdf_text(one_chip, False, "pallas", nsys=1024)
+    comps = _computations(text)
+    step = _reachable(comps, _loop_body(comps, "jit(<lambda>)/"))
+    newton = _reachable(comps, _loop_body(comps, "ensemble_bdf.newton/"))
+    kernels = [s for c in step for s in comps[c]
+               if "tpu_custom_call" in s and _opcode(s) == "custom-call"]
+    assert len(kernels) >= 5
+    for s in kernels:
+        assert _phase(s) in PHASES, s[:120]
+    fusions = [s for c in newton for s in comps[c] if _opcode(s) == "fusion"]
+    assert fusions
+    for s in fusions:
+        assert _phase(s) == "ensemble_bdf.newton", s[:120]
+    # reducers (``to_apply=``) and fused bodies are not run on their own
+    inner = {n for lines in comps.values() for s in lines
+             for n in re.findall(r"to_apply=%([\w.\-]+)", s)}
+    run = [(c, s) for c in comps
+           if "fused_computation" not in c and c not in inner
+           for s in comps[c] if _opcode(s) not in _NOT_RUN]
+    in_step = [s for c, s in run if c in step]
+    unscoped = [s for s in in_step if _phase(s) is None]
+    for s in in_step:
+        assert _phase(s) in PHASES or _opcode(s) in _XLA_OWN, s[:120]
+    print(f"{len(unscoped)} of {len(in_step)} step-loop instructions "
+          f"unscoped (XLA's own copies, prefetches and broadcasts); "
+          f"{sum(_phase(s) is None for _, s in run)} of {len(run)} in all")
 
 
 def test_device_kind_table():
