@@ -125,9 +125,10 @@ def _cases():
                 yield "wrms_soa", (zz, ww)
             if nsys == 4096:
                 q1 = 6
-                Wh = rnd(26, (q1, q1, nsys))
+                eh = jnp.exp(0.5 * rnd(26, (nsys,)))
+                qh = jnp.full((nsys,), q1 - 1, jnp.int32)
                 Zh = rnd(27, (q1, n, nsys))
-                yield "history_rescale_soa", (Wh, Zh, mb)
+                yield "lagrange_rescale_soa", (eh, qh, Zh, mb)
     # sparse: banded CSR + a small shared-pattern BSR ensemble
     from repro.core.sunmatrix import SparseCSR
     for ncsr in (133, 1024):

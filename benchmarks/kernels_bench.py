@@ -94,7 +94,9 @@ def smoke(n: int = 4096, tol: float = 1e-5):
     wb = jnp.abs(jax.random.normal(jax.random.PRNGKey(12), (bs, nb))) + 0.1
     mb = jax.random.uniform(jax.random.PRNGKey(13), (nb,)) > 0.4
     q1 = 6
-    Wh = jax.random.normal(jax.random.PRNGKey(14), (q1, q1, nb))
+    eh = jnp.exp(jax.random.uniform(jax.random.PRNGKey(14), (nb,),
+                                    minval=-0.7, maxval=0.7))
+    qh = jax.random.randint(jax.random.PRNGKey(16), (nb,), 0, q1)
     Zh = jax.random.normal(jax.random.PRNGKey(15), (q1, bs, nb))
     # sparse ops: a banded CSR pattern (non-lane-multiple rows) and a
     # shared block pattern with a ragged system batch
@@ -136,8 +138,8 @@ def smoke(n: int = 4096, tol: float = 1e-5):
         "masked_update_wrms_soa": lambda p: jnp.concatenate(
             [x.ravel() for x in dp.masked_update_wrms_soa(rb, rb, wb,
                                                           mb, p)]),
-        "history_rescale_soa": lambda p: dp.history_rescale_soa(
-            Wh, Zh, mb, p),
+        "lagrange_rescale_soa": lambda p: dp.lagrange_rescale_soa(
+            eh, qh, Zh, mb, p),
         "wrms_soa": lambda p: dp.wrms_soa(rb, wb, p),
         "csr_spmv": lambda p: dp.csr_spmv(csr.data, xs, csr.pattern, p),
         "bsr_spmv_soa": lambda p: dp.bsr_spmv_soa(Vb, xb, bpat, p),

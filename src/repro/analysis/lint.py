@@ -301,7 +301,7 @@ def default_contract_sigs() -> Dict[str, list]:
         for op in ("newton_residual_soa", "masked_update_wrms_soa",
                    "wrms_soa"):
             add(op, n=n, nsys=nsys)
-    add("history_rescale_soa", n=3, nsys=8, k=6)
+    add("lagrange_rescale_soa", n=3, nsys=8, k=6)
     for n in (4, 8):
         add("csr_spmv", n=n, nnz=3 * n - 2)
     for nblk, b, nsys in ((4, 3, 8),):

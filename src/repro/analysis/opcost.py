@@ -66,7 +66,7 @@ LANE = 128
 #: reduce_tile).
 BATCHED_OPS = frozenset({
     "block_solve_soa", "block_inverse_soa", "blockdiag_spmv_soa",
-    "newton_residual_soa", "masked_update_wrms_soa", "history_rescale_soa",
+    "newton_residual_soa", "masked_update_wrms_soa", "lagrange_rescale_soa",
     "wrms_soa", "bsr_spmv_soa", "bsr_block_jacobi_inverse_soa",
 })
 
@@ -156,8 +156,8 @@ def _sig_soa_elementwise(op: str, args: Tuple) -> OpSig:
     return OpSig(op, str(z.dtype), n=n, nsys=nsys)
 
 
-def _sig_history_rescale(op: str, args: Tuple) -> OpSig:
-    _W, Z, _active = args
+def _sig_lagrange_rescale(op: str, args: Tuple) -> OpSig:
+    _eta, _q, Z, _active = args
     q1, n, nsys = Z.shape
     return OpSig(op, str(Z.dtype), n=n, nsys=nsys, k=q1)
 
@@ -199,7 +199,7 @@ SIG_EXTRACTORS = {
     "newton_residual_soa": _sig_soa_elementwise,
     "masked_update_wrms_soa": _sig_soa_elementwise,
     "wrms_soa": _sig_soa_elementwise,
-    "history_rescale_soa": _sig_history_rescale,
+    "lagrange_rescale_soa": _sig_lagrange_rescale,
     "csr_spmv": _sig_csr,
     "bsr_spmv_soa": _sig_bsr_spmv,
     "bsr_block_jacobi_inverse_soa": _sig_bsr_diag_inverse,
@@ -301,11 +301,15 @@ def _cost_masked_update_wrms(sig: OpSig) -> OpCost:
     return OpCost(6 * n * nsys, io, io, io, 6, 6, 5 * n)
 
 
-def _cost_history_rescale(sig: OpSig) -> OpCost:
+def _cost_lagrange_rescale(sig: OpSig) -> OpCost:
     s, n, nsys, k = sig.itemsize, sig.n, sig.nsys, sig.k
-    io = (2 * k * n + k * k) * nsys * s
-    return OpCost(2 * k * k * n * nsys, io, io, io, 2 * k, 2 * k,
-                  2 * k * n + k * k)
+    # Z in and out plus the eta, q and active rows; the kernel makes the
+    # k x k weights of each lane in VMEM (about k^3 operations), while
+    # the oracle writes and reads them
+    io = (2 * k * n + 3) * nsys * s
+    wts = 2 * k * k * nsys * s
+    return OpCost((2 * k * k * n + k ** 3) * nsys, io, io + wts, io + wts,
+                  2 * k, 2 * k, 2 * k * n + k * k + 2)
 
 
 def _cost_wrms_soa(sig: OpSig) -> OpCost:
@@ -358,7 +362,7 @@ COST_MODELS = {
     "blockdiag_spmv_soa": _cost_blockdiag_spmv,
     "newton_residual_soa": _cost_newton_residual,
     "masked_update_wrms_soa": _cost_masked_update_wrms,
-    "history_rescale_soa": _cost_history_rescale,
+    "lagrange_rescale_soa": _cost_lagrange_rescale,
     "wrms_soa": _cost_wrms_soa,
     "csr_spmv": _cost_csr_spmv,
     "bsr_spmv_soa": _cost_bsr_spmv,

@@ -115,12 +115,13 @@ def _f_masked_update(sig):
             lambda fn, a, pol: fn(a[0], a[1], a[2], a[3], policy=pol))
 
 
-def _f_history_rescale(sig):
-    W = _sds((sig.k, sig.k, sig.nsys), sig.dtype)
+def _f_lagrange_rescale(sig):
+    eta = _sds((sig.nsys,), sig.dtype)
+    q = _sds((sig.nsys,), jnp.int32)
     Z = _sds((sig.k, sig.n, sig.nsys), sig.dtype)
     act = _sds((sig.nsys,), jnp.bool_)
-    return ((W, Z, act),
-            lambda fn, a, pol: fn(a[0], a[1], a[2], policy=pol))
+    return ((eta, q, Z, act),
+            lambda fn, a, pol: fn(a[0], a[1], a[2], a[3], policy=pol))
 
 
 def _f_wrms_soa(sig):
@@ -168,7 +169,7 @@ ARG_FACTORIES = {
     "blockdiag_spmv_soa": _f_block_solve,
     "newton_residual_soa": _f_newton_residual,
     "masked_update_wrms_soa": _f_masked_update,
-    "history_rescale_soa": _f_history_rescale,
+    "lagrange_rescale_soa": _f_lagrange_rescale,
     "wrms_soa": _f_wrms_soa,
     "csr_spmv": _f_csr_spmv,
     "bsr_spmv_soa": _f_bsr_spmv,

@@ -41,7 +41,7 @@ evaluation instead of spread over every op).
 
 The per-iteration work runs through three fused dispatch ops
 (``newton_residual_soa``, ``masked_update_wrms_soa``,
-``history_rescale_soa``; see :mod:`repro.kernels.newton`), and the BDF
+``lagrange_rescale_soa``; see :mod:`repro.kernels.newton`), and the BDF
 step loop is executed with its carry **donated** so XLA updates the
 history window in place instead of double-buffering it.
 """
@@ -558,8 +558,10 @@ def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
     rhs ``-g`` in a single HBM pass), one lsolve, and one fused masked
     update + correction norm (``masked_update_wrms_soa``).  The
     twice-per-step Lagrange history rebuild runs through
-    ``history_rescale_soa``, which short-circuits bundles with no
-    active system instead of sweeping the full (QMAX+1, n, nsys) window.
+    ``lagrange_rescale_soa``, which takes each system's step ratio and
+    valid depth (the pallas kernel makes the weights itself) and
+    short-circuits bundles with no active system instead of sweeping
+    the full (QMAX+1, n, nsys) window.
     ``f_soa`` / ``jac_soa`` (signatures ``(t:(nsys,), y:(n,nsys)) ->
     (n,nsys)`` and ``-> (n,n,nsys)``) supply native SoA RHS/Jacobian
     forms; without them the AoS callables are wrapped with a transpose
@@ -739,16 +741,14 @@ def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
         with jax.named_scope("ensemble_bdf.rescale"):
             # if h was clipped to hit tf, rescale the history accordingly
             # (fused masked rebuild).  Unclipped systems have eta_clip ==
-            # 1.0 exactly (hs == c.h -> hs/c.h == 1.0) and
-            # _lagrange_matrix at eta=1 is the exact identity, so masking
-            # them out is a value-level no-op that lets the kernel
-            # short-circuit whole bundles in the common no-clip case
-            # instead of sweeping the full (QMAX+1, n, nsys) window
-            # every step
+            # 1.0 exactly (hs == c.h -> hs/c.h == 1.0) and the Lagrange
+            # matrix at eta=1 is the exact identity, so masking them out
+            # is a value-level no-op that lets the kernel short-circuit
+            # whole bundles in the common no-clip case instead of
+            # sweeping the full (QMAX+1, n, nsys) window every step
             eta_clip = jnp.where(active, hs / c.h, one)
-            W = jax.vmap(_cv._lagrange_matrix)(eta_clip, nvalid)
-            Z = dv.history_rescale_soa(jnp.transpose(W, (1, 2, 0)), c.Z,
-                                       active & (eta_clip != one), policy)
+            Z = dv.lagrange_rescale_soa(eta_clip, nvalid, c.Z,
+                                        active & (eta_clip != one), policy)
         with jax.named_scope("ensemble_bdf.predict"):
             qi = c.q - 1
             alphas = jnp.asarray(_cv._ALPHA_T, dtype)[qi]  # (nsys, QMAX+1)
@@ -877,10 +877,9 @@ def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
         with jax.named_scope("ensemble_bdf.rescale"):
             nval_after = jnp.minimum(c.steps + accept.astype(jnp.int32),
                                      QMAX)
-            W2 = jax.vmap(_cv._lagrange_matrix)(
-                jnp.where(active, eta, one), nval_after)
-            Z_next = dv.history_rescale_soa(jnp.transpose(W2, (1, 2, 0)),
-                                            Z_next, active, policy)
+            Z_next = dv.lagrange_rescale_soa(jnp.where(active, eta, one),
+                                             nval_after, Z_next, active,
+                                             policy)
 
         with jax.named_scope("ensemble_bdf.update"):
             t_next = jnp.where(accept, t_new, c.t)

@@ -38,6 +38,7 @@ import functools
 import inspect
 from typing import Any, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 from jax import tree_util
 
@@ -297,16 +298,21 @@ def _pl_masked_update_wrms_soa(z, dz, w, mask, *, policy: ExecPolicy):
                                        interpret=policy.interpreted())
 
 
-def _jnp_history_rescale_soa(W, Z, active, *, policy=None):
+def _jnp_lagrange_rescale_soa(eta, q, Z, active, *, policy=None):
+    # the per-lane Lagrange matrices, then the masked AoS einsum: the
+    # integrator's pre-kernel expression, kept bitwise
+    from repro.core import cvode as _cv
     from repro.kernels import ref as kref
-    return kref.history_rescale_soa_ref(W, Z, active)
+    W = jax.vmap(_cv._lagrange_matrix)(eta, q)
+    return kref.history_rescale_soa_ref(jnp.transpose(W, (1, 2, 0)), Z,
+                                        active)
 
 
-def _pl_history_rescale_soa(W, Z, active, *, policy: ExecPolicy):
+def _pl_lagrange_rescale_soa(eta, q, Z, active, *, policy: ExecPolicy):
     from repro.kernels import ops as kops
-    return kops.history_rescale_soa(W, Z, active,
-                                    batch_tile=policy.batch_tile,
-                                    interpret=policy.interpreted())
+    return kops.lagrange_rescale_soa(eta, q, Z, active,
+                                     batch_tile=policy.batch_tile,
+                                     interpret=policy.interpreted())
 
 
 def _jnp_wrms_soa(v, w, *, policy=None):
@@ -417,8 +423,8 @@ OP_TABLE = {
                             "pallas": _pl_newton_residual_soa},
     "masked_update_wrms_soa": {"jnp": _jnp_masked_update_wrms_soa,
                                "pallas": _pl_masked_update_wrms_soa},
-    "history_rescale_soa": {"jnp": _jnp_history_rescale_soa,
-                            "pallas": _pl_history_rescale_soa},
+    "lagrange_rescale_soa": {"jnp": _jnp_lagrange_rescale_soa,
+                             "pallas": _pl_lagrange_rescale_soa},
     "wrms_soa": {"jnp": _jnp_wrms_soa, "pallas": _pl_wrms_soa},
     # sparse matrices (static shared patterns)
     "csr_spmv": {"jnp": _jnp_csr_spmv, "pallas": _pl_csr_spmv},
@@ -572,8 +578,8 @@ OP_NOTES = {
                             "newton fused residual"),
     "masked_update_wrms_soa": ("ref (where + wrms)",
                                "newton fused update+WRMS"),
-    "history_rescale_soa": ("ref (masked AoS einsum)",
-                            "newton masked rebuild"),
+    "lagrange_rescale_soa": ("Lagrange W + AoS einsum",
+                             "newton in-kernel weights"),
     "wrms_soa": ("ref (per-system WRMS)", "newton wrms_soa kernel"),
     "csr_spmv": ("segment_sum", "sparse ELL gather kernel"),
     "bsr_spmv_soa": ("einsum+segment_sum", "sparse unrolled-pattern"),
@@ -698,13 +704,17 @@ def masked_update_wrms_soa(z: jnp.ndarray, dz: jnp.ndarray,
     return dispatch("masked_update_wrms_soa", policy)(z, dz, w, mask)
 
 
-def history_rescale_soa(W: jnp.ndarray, Z: jnp.ndarray,
-                        active: jnp.ndarray,
-                        policy: Optional[ExecPolicy] = None) -> jnp.ndarray:
-    """Masked per-system Lagrange history rebuild: W (q1,q1,nsys),
-    Z (q1,n,nsys) -> where(active, sum_i W[j,i]*Z[i], Z[j]); inactive
-    bundles are short-circuited on the pallas backend."""
-    return dispatch("history_rescale_soa", policy)(W, Z, active)
+def lagrange_rescale_soa(eta: jnp.ndarray, q: jnp.ndarray,
+                         Z: jnp.ndarray, active: jnp.ndarray,
+                         policy: Optional[ExecPolicy] = None
+                         ) -> jnp.ndarray:
+    """Masked per-system Lagrange history rebuild onto a step ``eta``
+    times the old one, over the ``q``-deep valid history: eta, q, active
+    (nsys,), Z (q1,n,nsys) -> where(active, sum_i W[j,i]*Z[i], Z[j])
+    with W = ``cvode._lagrange_matrix(eta, q)`` per system.  The pallas
+    backend makes the weights in the kernel and short-circuits bundles
+    with no active lane off eta == 1."""
+    return dispatch("lagrange_rescale_soa", policy)(eta, q, Z, active)
 
 
 def wrms_soa(v: jnp.ndarray, w: jnp.ndarray,

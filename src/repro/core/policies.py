@@ -33,7 +33,7 @@ block_inverse_soa             ref.block_inverse_soa_ref    GJ inverse (b>8: row-
 blockdiag_spmv_soa            jnp.einsum                   blockdiag_spmv kernel
 newton_residual_soa           ref (z - gamma*f - psi)      newton fused residual
 masked_update_wrms_soa        ref (where + wrms)           newton fused update+WRMS
-history_rescale_soa           ref (masked AoS einsum)      newton masked rebuild
+lagrange_rescale_soa          Lagrange W + AoS einsum      newton in-kernel weights
 wrms_soa                      ref (per-system WRMS)        newton wrms_soa kernel
 csr_spmv                      segment_sum                  sparse ELL gather kernel
 bsr_spmv_soa                  einsum+segment_sum           sparse unrolled-pattern

@@ -13,12 +13,13 @@ each is exactly one pass:
   ``z += dz (where mask)`` FUSED with the per-system WRMS of ``dz``:
   the correction is read once from HBM instead of once for the update
   and once for the convergence-rate reduction;
-* :func:`history_rescale` — the Lagrange history rebuild
-  ``Z_new[j] = sum_i W[j,i] * Z[i]`` as a lane-parallel kernel that
-  SHORT-CIRCUITS inactive systems: a bundle whose systems are all
-  masked (finished, or unclipped steps with identity W) copies Z
-  through instead of running the (QMAX+1)^2 multiply-add sweep, and
-  inactive lanes inside a live bundle pass through unchanged;
+* :func:`lagrange_rescale` — the Lagrange history rebuild
+  ``Z_new[j] = sum_i W[j,i] * Z[i]`` from each lane's step ratio and
+  valid depth: the weights are made in the kernel, so one pass reads
+  and writes Z and nothing of W touches HBM; lanes that keep their
+  step (inactive, or eta == 1) pass through unchanged, and a bundle
+  with no other lane copies Z through instead of running the
+  (QMAX+1)^2 multiply-add sweep;
 * :func:`wrms_soa` — the per-system WRMS reduction ``(n, NB) -> (NB,)``
   (the batched row of the N_VWrmsNorm family; the BDF error test and
   the DIRK residual checks go through it).
@@ -33,9 +34,12 @@ it.
 from __future__ import annotations
 
 import functools
+import math
+import operator
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 from . import LANE, grid_block, resolve_interpret
@@ -114,49 +118,93 @@ def masked_update_wrms(z: jnp.ndarray, dz: jnp.ndarray, w: jnp.ndarray,
     return z_new, dn.reshape(NB)
 
 
-def _history_rescale_kernel(w_ref, z_ref, a_ref, out_ref, *, q1: int):
-    act = a_ref[...] > 0.5                   # (1, TN)
+def reciprocal_denominators(q1: int, dtype) -> list:
+    """``r[q][i]``: one over the Lagrange denominator of old node ``i``
+    at depth ``q``, ``prod_{k != i, k <= q} (k - i) = (-1)^i i! (q-i)!``,
+    rounded once to ``dtype``; 0 for columns ``i > q``.
 
-    @pl.when(jnp.max(a_ref[...]) > 0.5)
+    Each denominator is an integer of at most 120, exact in ``dtype``,
+    and ``den * r`` rounds to exactly 1: a weight whose numerator
+    product equals its denominator (the diagonal at eta = 1, the
+    ``(0, 0)`` weight at any eta) comes out exactly 1."""
+    import numpy as np
+    one = np.ones((), dtype)
+    return [[float(one / np.asarray((-1) ** i * math.factorial(i) *
+                                    math.factorial(q - i), dtype))
+             if i <= q else 0.0 for i in range(q1)] for q in range(q1)]
+
+
+def _lagrange_rescale_kernel(eta_ref, q_ref, z_ref, out_ref, *, rden):
+    """Z_new[j] = sum_i W[j,i] Z[i] with each lane's weights made here.
+
+    The new node index j rides the sublanes, so every weight column
+    ``W[:, i]`` is one (q1, TN) value:
+    ``W[j,i] = prod_{k != i, k <= q} (k - j*eta) * r[q][i]``, with rows
+    ``j > q`` the identity.  Lanes at eta == 1 (unchanged step, or
+    inactive: the wrapper sends them at eta 1) copy Z through, and a
+    tile with no other lane skips the arithmetic."""
+    q1, _, tn = z_ref.shape
+    eta = eta_ref[...]                        # (1, TN)
+    live = eta != 1.0
+    any_live = jnp.max(live.astype(jnp.float32))
+
+    @pl.when(any_live > 0.5)
     def _():
-        for j in range(q1):
-            acc = w_ref[j, 0:1, :] * z_ref[0]
+        q = q_ref[...]                        # (1, TN), the valid depth
+        j = lax.broadcasted_iota(jnp.int32, (q1, tn), 0).astype(eta.dtype)
+        p = j * eta                           # new nodes sit at -j*eta
+        # the numerator's factors, 1 beyond the valid depth
+        f = [jnp.where(q >= k, k - p, 1.0) for k in range(q1)]
+        z = [z_ref[i] for i in range(q1)]
+        cols = []
+        for i in range(q1):
+            num = functools.reduce(operator.mul,
+                                   [f[k] for k in range(q1) if k != i])
+            r = jnp.zeros_like(eta)
+            for qv in range(i, q1):
+                r = jnp.where(q == qv, rden[qv][i], r)
+            cols.append(jnp.where(j > q, (j == i).astype(eta.dtype),
+                                  num * r))
+        for jj in range(q1):
+            acc = cols[0][jj:jj + 1] * z[0]
             for i in range(1, q1):
-                acc = acc + w_ref[j, i:i + 1, :] * z_ref[i]
-            out_ref[j] = jnp.where(act, acc, z_ref[j])
+                acc = acc + cols[i][jj:jj + 1] * z[i]
+            out_ref[jj] = jnp.where(live, acc, z[jj])
 
-    @pl.when(jnp.max(a_ref[...]) <= 0.5)
+    @pl.when(any_live <= 0.5)
     def _():
         out_ref[...] = z_ref[...]
 
 
-def history_rescale(W: jnp.ndarray, Z: jnp.ndarray, active: jnp.ndarray,
-                    *, batch_tile: int = 4 * LANE,
-                    interpret=None) -> jnp.ndarray:
-    """Lane-parallel Lagrange history rebuild with inactive short-circuit.
+def lagrange_rescale(eta: jnp.ndarray, q: jnp.ndarray, Z: jnp.ndarray,
+                     *, batch_tile: int = 4 * LANE,
+                     interpret=None) -> jnp.ndarray:
+    """Lane-parallel Lagrange history rebuild from per-lane (eta, q).
 
-    W: (q1, q1, NB) per-system rescale matrices, Z: (q1, n, NB) history,
-    active: (NB,) (nonzero = rescale) -> Z_new with
-    Z_new[j,k,s] = sum_i W[j,i,s] * Z[i,k,s] where active, else Z[j,k,s].
-    A bundle tile with NO active system skips the q1^2 multiply-add
-    sweep entirely and copies Z through (the common case between step
-    rejections and once most systems reach tf).
+    eta: (NB,) step ratio (1 where a lane is not to change), q: (NB,)
+    valid history depth (as Z's dtype), Z: (q1, n, NB) history ->
+    Z_new[j,k,s] = sum_i W(eta_s, q_s)[j,i] * Z[i,k,s], where W is the
+    Lagrange matrix that moves the old nodes -i onto the new nodes
+    -j*eta (the weights are made in the kernel: no (q1, q1, NB) weight
+    tensor is read).  Lanes at eta == 1 return Z bit-exactly; a bundle
+    tile with no other lane skips the weights and the multiply-adds.
     """
-    q1, q1b, NB = W.shape
-    _, n, _ = Z.shape
-    assert q1 == q1b and Z.shape == (q1, n, NB)
-    assert active.shape == (NB,) and NB % batch_tile == 0
-    kernel = functools.partial(_history_rescale_kernel, q1=q1)
+    q1, n, NB = Z.shape
+    assert eta.shape == (NB,) and q.shape == (NB,)
+    assert NB % batch_tile == 0
+    kernel = functools.partial(
+        _lagrange_rescale_kernel,
+        rden=reciprocal_denominators(q1, Z.dtype))
     hist = grid_block((q1, n, batch_tile))
+    lanes = grid_block((1, batch_tile))
     return pl.pallas_call(
         kernel,
         grid=(NB // batch_tile,),
-        in_specs=[grid_block((q1, q1, batch_tile)), hist,
-                  grid_block((1, batch_tile))],
+        in_specs=[lanes, lanes, hist],
         out_specs=hist,
         out_shape=jax.ShapeDtypeStruct((q1, n, NB), Z.dtype),
         interpret=resolve_interpret(interpret),
-    )(W, Z, _row(active))
+    )(_row(eta), _row(q), Z)
 
 
 def _wrms_soa_kernel(v_ref, w_ref, out_ref, *, n: int):

@@ -343,19 +343,22 @@ def masked_update_wrms_soa(z: jnp.ndarray, dz: jnp.ndarray, w: jnp.ndarray,
 
 
 @functools.partial(jax.jit, static_argnames=("batch_tile", "interpret"))
-def history_rescale_soa(W: jnp.ndarray, Z: jnp.ndarray,
-                        active: jnp.ndarray, *,
-                        batch_tile: int = 4 * LANE,
-                        interpret=None):
-    """Masked Lagrange history rebuild: W (q1,q1,NB), Z (q1,n,NB),
-    active (NB,) -> Z_new; padded systems are inactive (Z copied)."""
-    q1, _, nb = W.shape
+def lagrange_rescale_soa(eta: jnp.ndarray, q: jnp.ndarray, Z: jnp.ndarray,
+                         active: jnp.ndarray, *,
+                         batch_tile: int = 4 * LANE,
+                         interpret=None):
+    """Masked Lagrange history rebuild from per-lane step ratio ``eta``
+    and valid depth ``q`` (any real or integer dtype), Z (q1,n,NB),
+    active (NB,) -> Z_new; inactive and padded systems go to the kernel
+    at eta = 1, which copies their Z through."""
+    q1, _, nb = Z.shape
     tile = _batch_tile(nb, batch_tile)
-    Wp, _ = _pad_to(W, tile, axis=2)
+    e = jnp.where(active != 0, eta, 1).astype(Z.dtype)
+    ep, _ = _pad_to(e, tile, axis=0, fill=1.0)
+    qp, _ = _pad_to(q.astype(Z.dtype), tile, axis=0)
     Zp, _ = _pad_to(Z, tile, axis=2)
-    ap, _ = _pad_to(active.astype(Z.dtype), tile, axis=0)
-    Zn = _nw.history_rescale(Wp, Zp, ap, batch_tile=tile,
-                             interpret=interpret)
+    Zn = _nw.lagrange_rescale(ep, qp, Zp, batch_tile=tile,
+                              interpret=interpret)
     return Zn[:, :, :nb]
 
 

@@ -84,7 +84,8 @@ def test_opcost_signature_covers_every_op():
     z = jnp.ones((b, nsys))
     gm = jnp.ones((nsys,))
     mk = jnp.ones((nsys,), bool)
-    Wh = jnp.ones((6, 6, nsys))
+    eh = jnp.ones((nsys,))
+    qh = jnp.full((nsys,), 5, jnp.int32)
     Zh = jnp.ones((6, b, nsys))
     data = jnp.ones((17,))
     pat = (tuple(range(5)), tuple(range(5)), 5)
@@ -100,7 +101,7 @@ def test_opcost_signature_covers_every_op():
         "blockdiag_spmv_soa": (A, r),
         "newton_residual_soa": (z, z, z, gm, True),
         "masked_update_wrms_soa": (z, z, z, mk),
-        "history_rescale_soa": (Wh, Zh, mk), "wrms_soa": (z, z),
+        "lagrange_rescale_soa": (eh, qh, Zh, mk), "wrms_soa": (z, z),
         "csr_spmv": (data, x, None), "bsr_spmv_soa": (Vb, xb, pat),
         "bsr_block_jacobi_inverse_soa": (Vb, pat),
     }

@@ -121,6 +121,30 @@ def test_bdf_kinetics_jnp_vs_pallas_parity(factor_once):
     assert float(jnp.max(jnp.abs(jnp.sum(y_p, 1) - 1.0))) < 1e-4
 
 
+def test_bdf_per_lane_tf_clip_rescale_parity():
+    """Lanes with their own tf: each lane's last step is clipped to its
+    tf on its own trip, so the clip-site history rescale runs for some
+    lanes of a bundle tile and passes the others through.  jnp oracle
+    vs the Pallas(interpret) kernels, in the tolerance form of
+    :func:`test_bdf_kinetics_jnp_vs_pallas_parity` with C = 10: the two
+    backends agree to within 1.2 of the control's units here, while a
+    kernel path that skipped the clip rescale lands 30-40 units off."""
+    nsys = 130
+    f, jac, y0 = _kinetics(nsys)
+    tf = jnp.linspace(2.0, 10.0, nsys)
+    opts = ODEOptions(rtol=1e-5, atol=1e-10, max_steps=100_000)
+    y_j, st_j = batched.ensemble_bdf_integrate(
+        f, jac, y0, 0.0, tf, opts=opts, policy=XLA_FUSED)
+    pol = ExecPolicy(backend="pallas", interpret=True, batch_tile=128)
+    y_p, st_p = batched.ensemble_bdf_integrate(
+        f, jac, y0, 0.0, tf, opts=opts, policy=pol)
+    assert bool(jnp.all(st_j.success)) and bool(jnp.all(st_p.success))
+    # the lanes finish on different trips: the clip engages lane by lane
+    assert len(np.unique(np.asarray(st_p.steps))) > 1
+    np.testing.assert_allclose(np.asarray(y_j), np.asarray(y_p),
+                               rtol=10 * opts.rtol, atol=10 * opts.atol)
+
+
 def test_bdf_matches_scalar_cvode_reference():
     """One system of the ensemble path vs the scalar CVODE analog."""
     from repro.core import cvode

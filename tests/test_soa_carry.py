@@ -8,8 +8,9 @@
    oracle the jnp path is pinned to.  Native SoA RHS/Jacobian forms
    (``batched_robertson_soa``) must land on the same bits as the
    wrapped AoS forms.
-2. jnp-vs-pallas(interpret) parity at 1e-10 for the three new fused
-   Newton ops (+ the per-system ``wrms_soa``) with ragged batches.
+2. jnp-vs-pallas(interpret) parity at 1e-10 for the fused Newton ops
+   (+ the per-system ``wrms_soa`` and the Lagrange history rescale)
+   with ragged batches; the rescale kernel's weights exact at eta = 1.
 3. Layout gate: sunlint's ``hot-loop-layout`` jaxpr rule proves the
    traced Newton ``while_loop`` bodies (BDF and DIRK) contain no
    transposes or copying reshapes — replacing the old source grep,
@@ -257,7 +258,13 @@ def test_fused_newton_ops_parity_ragged(nb, tile):
     gam = jnp.abs(jax.random.normal(jax.random.PRNGKey(3), (nb,)))
     w = jnp.abs(jax.random.normal(jax.random.PRNGKey(4), (n, nb))) + 0.1
     m = jax.random.uniform(jax.random.PRNGKey(5), (nb,)) > 0.4
-    W = jax.random.normal(jax.random.PRNGKey(6), (q1, q1, nb))
+    # step ratios over the controller's [0.1, 10], a fifth of the lanes
+    # at exactly 1 (an unchanged step), every valid depth 0..QMAX
+    eta = jnp.exp(jax.random.uniform(jax.random.PRNGKey(6), (nb,),
+                                     minval=np.log(0.1),
+                                     maxval=np.log(10.0)))
+    eta = eta.at[::5].set(1.0)
+    qv = jax.random.randint(jax.random.PRNGKey(8), (nb,), 0, q1)
     Z = jax.random.normal(jax.random.PRNGKey(7), (q1, n, nb))
 
     for negate in (False, True):
@@ -272,19 +279,59 @@ def test_fused_newton_ops_parity_ragged(nb, tile):
                                rtol=0, atol=1e-10)
     np.testing.assert_allclose(np.asarray(dna), np.asarray(dnb),
                                rtol=0, atol=1e-10)
-    ra = dv.history_rescale_soa(W, Z, m, XLA_FUSED)
-    rb = dv.history_rescale_soa(W, Z, m, pol)
-    np.testing.assert_allclose(np.asarray(ra), np.asarray(rb),
-                               rtol=0, atol=1e-10)
-    # inactive systems pass through bit-exactly on both backends
-    assert np.array_equal(np.asarray(ra[:, :, ~np.asarray(m)]),
-                          np.asarray(Z[:, :, ~np.asarray(m)]))
-    r0 = dv.history_rescale_soa(W, Z, jnp.zeros((nb,), bool), pol)
+    ra = np.asarray(dv.lagrange_rescale_soa(eta, qv, Z, m, XLA_FUSED))
+    rb = np.asarray(dv.lagrange_rescale_soa(eta, qv, Z, m, pol))
+    # 1e-10 in units of the sum's own size: far from eta = 1 the weights
+    # reach 1e7 and the terms cancel
+    W = np.asarray(jax.vmap(_cv._lagrange_matrix)(eta, qv))
+    size = 1.0 + np.einsum("sji,iks->jks", np.abs(W), np.abs(np.asarray(Z)))
+    assert np.max(np.abs(ra - rb) / size) <= 1e-10
+    # inactive systems pass through bit-exactly on both backends, and
+    # so do active ones at eta = 1 on the kernel's
+    off = ~np.asarray(m)
+    same = off | (np.asarray(eta) == 1.0)
+    assert np.array_equal(ra[:, :, off], np.asarray(Z)[:, :, off])
+    assert np.array_equal(rb[:, :, same], np.asarray(Z)[:, :, same])
+    r0 = dv.lagrange_rescale_soa(eta, qv, Z, jnp.zeros((nb,), bool), pol)
     assert np.array_equal(np.asarray(r0), np.asarray(Z))
     wa = dv.wrms_soa(z, w, XLA_FUSED)
     wb = dv.wrms_soa(z, w, pol)
     np.testing.assert_allclose(np.asarray(wa), np.asarray(wb),
                                rtol=0, atol=1e-10)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_lagrange_rescale_exact_at_unit_ratio(dtype):
+    """The kernel's weights: each reciprocal denominator times its
+    integer denominator rounds to exactly 1, so at eta = 1 the weights
+    are the exact identity and row 0 is an exact delta at any eta; the
+    jnp oracle's matrix agrees with them at every depth."""
+    import math
+    from repro.kernels import newton as knw
+    q1 = _cv.QMAX + 1
+    r = knw.reciprocal_denominators(q1, dtype)
+    for q in range(q1):
+        for i in range(q1):
+            den = (-1) ** i * math.factorial(i) * math.factorial(q - i) \
+                if i <= q else 0
+            assert dtype(den) * dtype(r[q][i]) == (1 if i <= q else 0)
+    pol = ExecPolicy(backend="pallas", interpret=True, batch_tile=128)
+    nb = 3 * q1
+    qv = jnp.tile(jnp.arange(q1), 3)
+    eta = jnp.repeat(jnp.asarray([1.0, 0.5, 3.0], dtype), q1)
+    # the identity as the history: the result is W itself, lane by lane
+    Z = jnp.broadcast_to(jnp.eye(q1, dtype=dtype)[:, :, None], (q1, q1, nb))
+    on = jnp.ones((nb,), bool)
+    Wk = np.asarray(dv.lagrange_rescale_soa(eta, qv, Z, on, pol))
+    Wr = np.asarray(jax.vmap(_cv._lagrange_matrix)(eta, qv))
+    eye = np.eye(q1, dtype=dtype)
+    for s in range(nb):
+        if eta[s] == 1.0:
+            assert np.array_equal(Wk[:, :, s], eye)
+        assert np.array_equal(Wk[0, :, s], eye[0])
+        np.testing.assert_allclose(
+            Wk[:, :, s], Wr[s], rtol=0,
+            atol=16 * np.finfo(dtype).eps * np.abs(Wr[s]).max())
 
 
 # ---------------------------------------------------------------------------

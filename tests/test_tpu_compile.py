@@ -74,9 +74,10 @@ NEWTON = {
         lambda z, d, w, m: ops.masked_update_wrms_soa(
             z, d, w, m, interpret=False),
         [(3, NSYS)] * 3 + [(NSYS,)]),
-    "history_rescale_soa": (
-        lambda W, Z, a: ops.history_rescale_soa(W, Z, a, interpret=False),
-        [(6, 6, NSYS), (6, 3, NSYS), (NSYS,)]),
+    "lagrange_rescale_soa": (
+        lambda e, q, Z, a: ops.lagrange_rescale_soa(e, q, Z, a,
+                                                    interpret=False),
+        [(NSYS,), (NSYS,), (6, 3, NSYS), (NSYS,)]),
     "wrms_soa": (
         lambda v, w: ops.wrms_soa(v, w, interpret=False),
         [(3, NSYS)] * 2),
