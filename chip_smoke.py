@@ -18,9 +18,10 @@ Pallas kernels compiled for the chip:
 * serving    -- a ``SolverServer`` answering 64 requests and one
   warm-start continuation under the compiled Pallas policy.
 
-``--four-chips`` runs only the sharded ensemble
-(``ensemble_bdf_integrate_sharded``, 4 x 1,048,576 systems) and the
-same systems on one chip to compare with.
+``--four-chips`` runs only the sharded ensemble (``integrate(...,
+"ensemble_bdf", mesh=...)`` with the native SoA Robertson family and
+the rates in ``IVP.params``, 4 x 1,048,576 systems) and the same
+systems on one chip to compare with.
 
 Answers are checked against a float64 jnp run of the same systems on the
 host CPU.  Everything runs in this one process, which starts no child.
@@ -334,35 +335,41 @@ def serving_phase(device) -> None:
 
 def sharded_phase(devs) -> None:
     """4 x NSYS systems with per-system params on the default
-    ('systems',) mesh, against the same systems on one chip."""
+    ('systems',) mesh, through ``integrate(..., mesh=)`` with the native
+    SoA family, against the same systems on one chip."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.core import batched, problems
+    from repro.core import problems
     from repro.core.arkode import ODEOptions
+    from repro.core.ivp import IVP, integrate
     from repro.core.linsol import BlockDiagGJ
     from repro.launch.mesh import make_ensemble_mesh
 
     nsys = 4 * NSYS
     policy = pallas_policy("sharded")
     opts = ODEOptions(rtol=RTOL, atol=ATOL, policy=policy)
-    f, jac, _, _ = problems.robertson_family()
+    f, jac, f_soa, jac_soa = problems.robertson_family()
     # inputs are held on the host, so that the memory each device holds
     # before the sharded run is only its baseline
     params = dict(zip(("k1", "k2", "k3"),
                       map(np.asarray, problems.robertson_rates(nsys))))
     y0 = np.zeros((nsys, 3), np.float32)
     y0[:, 0] = 1.0
-    kw = dict(opts=opts, policy=policy, linear_solver=BlockDiagGJ())
+    kw = dict(opts=opts, lin_solver=BlockDiagGJ())
 
     mesh = make_ensemble_mesh()
     shard = NamedSharding(mesh, P("systems"))
     before = [in_use(d) for d in devs]
     y0_s, params_s = jax.device_put((y0, params), shard)
 
+    def solve(y0, params, mesh=None):
+        sol = integrate(IVP(f=f, jac=jac, f_soa=f_soa, jac_soa=jac_soa,
+                            y0=y0, params=params), 0.0, TF, "ensemble_bdf",
+                        mesh=mesh, **kw)
+        return sol.y, sol.retcodes
+
     def sharded(y0, params):
-        y, st = batched.ensemble_bdf_integrate_sharded(
-            f, jac, y0, 0.0, TF, params=params, mesh=mesh, **kw)
-        return y, st.retcodes
+        return solve(y0, params, mesh)
 
     t0 = time.perf_counter()
     lowered = jax.jit(sharded).lower(y0_s, params_s)
@@ -372,16 +379,10 @@ def sharded_phase(devs) -> None:
     after = [in_use(d) for d in devs]
     shard_devs = {s.device for s in y_sh.addressable_shards}
 
-    def single(y0, params):
-        y, st = batched.ensemble_bdf_integrate(
-            lambda t, y: f(t, y, params), lambda t, y: jac(t, y, params),
-            y0, 0.0, TF, **kw)
-        return y, st.retcodes
-
     one = jax.sharding.SingleDeviceSharding(devs[0])
     y0_1, params_1 = jax.device_put((y0, params), one)
     t1 = time.perf_counter()
-    y_1, rc_1 = jax.block_until_ready(jax.jit(single)(y0_1, params_1))
+    y_1, rc_1 = jax.block_until_ready(jax.jit(solve)(y0_1, params_1))
     one_s = time.perf_counter() - t1
     y_sh, y_1 = np.asarray(y_sh), np.asarray(y_1)
     rc_sh, rc_1 = np.asarray(rc_sh), np.asarray(rc_1)

@@ -1040,7 +1040,10 @@ def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
 def ensemble_bdf_integrate_sharded(f: Callable, jac: Callable,
                                    y0: jnp.ndarray, t0, tf, *,
                                    params=None, mesh=None,
-                                   axis: str = "systems", **kw):
+                                   axis: str = "systems",
+                                   f_soa: Optional[Callable] = None,
+                                   jac_soa: Optional[Callable] = None,
+                                   **kw):
     """Shard :func:`ensemble_bdf_integrate` over the system axis.
 
     One call advances ``device_count x`` more systems: the batch is split
@@ -1050,29 +1053,31 @@ def ensemble_bdf_integrate_sharded(f: Callable, jac: Callable,
     whose systems finish early simply stops stepping).  This is the TPU
     expression of the paper's one-CVODE-instance-per-stream bundles, with
     the bundle size per device further tiled by ``ExecPolicy.batch_tile``.
+    :func:`repro.core.ivp.integrate` reaches it with ``mesh=``.
 
     params : optional pytree of per-system arrays (leading axis nsys),
-             sharded alongside ``y0``; ``f``/``jac`` are then called as
-             ``f(t, y, params_shard)``.  Closed-over global arrays sized
-             (nsys, ...) would NOT be sharded — route them through
-             ``params`` instead.
+             sharded alongside ``y0``; ``f``/``jac`` and ``f_soa``/
+             ``jac_soa`` are then called as ``f(t, y, params_shard)``.
+             Closed-over global arrays sized (nsys, ...) would NOT be
+             sharded — route them through ``params`` instead.
+    f_soa, jac_soa : optional native SoA forms (system axis last), in
+             the same form as ``f``/``jac``: each device evaluates them
+             on its ``(n, nsys / ndev)`` slice with no transpose.
+             Without them the AoS forms are wrapped with a transpose
+             inside each device's loop.
     mesh   : a 1-D ('systems',) mesh by default
              (:func:`repro.launch.mesh.make_ensemble_mesh`).
     If nsys is not a multiple of the device count the batch is padded
     with finished dummy systems (tf = t0: masked no-ops from step one).
+    The whole call runs under ``jax.named_scope("ensemble_bdf.shards")``:
+    the padding, the un-padding, the cross-shard ``nli``/``npsolves``
+    sums and each shard's work outside its step loop carry that scope
+    in a device trace; the step's own phases stay innermost.
+    ``session=``/``return_session=`` and ``telemetry=`` are refused.
     """
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_ensemble_mesh
 
-    # an explicit None is the documented "no native SoA form" default of
-    # the non-sharded API — only an actual callable is rejected here
-    if kw.pop("f_soa", None) is not None or \
-            kw.pop("jac_soa", None) is not None:
-        raise ValueError(
-            "ensemble_bdf_integrate_sharded takes the AoS f/jac only: a "
-            "native SoA callable would close over unsharded (.., nsys) "
-            "arrays; route per-system data through params= instead (the "
-            "per-shard SoA wrapping happens inside each device's loop)")
     if kw.pop("session", None) is not None or kw.pop("return_session",
                                                     False):
         raise ValueError(
@@ -1081,55 +1086,61 @@ def ensemble_bdf_integrate_sharded(f: Callable, jac: Callable,
             "close over the shard_map body unsharded; warm-start "
             "continuation is a serving-layer (single-mesh-shard) "
             "feature for now")
+    if kw.pop("telemetry", None) is not None:
+        raise ValueError(
+            "ensemble_bdf_integrate_sharded takes no telemetry=: the "
+            "step-telemetry ring is not sharded")
     if mesh is None:
         mesh = make_ensemble_mesh()
     ndev = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
-    nsys, n = y0.shape
-    dtype = y0.dtype
-    t0a = jnp.broadcast_to(jnp.asarray(t0, dtype), (nsys,))
-    tfa = jnp.broadcast_to(jnp.asarray(tf, dtype), (nsys,))
-    pad = (-nsys) % ndev
-    if pad:
-        y0 = jnp.concatenate([y0, jnp.broadcast_to(y0[-1:], (pad, n))])
-        t0a = jnp.concatenate([t0a, jnp.full((pad,), t0a[-1], dtype)])
-        # tf = t0 -> padded systems are inactive from the first cond
-        tfa = jnp.concatenate([tfa, jnp.full((pad,), t0a[-1], dtype)])
-        if params is not None:
-            params = jax.tree_util.tree_map(
-                lambda p: jnp.concatenate(
-                    [p, jnp.broadcast_to(p[-1:], (pad,) + p.shape[1:])]),
-                params)
+    with jax.named_scope("ensemble_bdf.shards"):
+        nsys, n = y0.shape
+        dtype = y0.dtype
+        t0a = jnp.broadcast_to(jnp.asarray(t0, dtype), (nsys,))
+        tfa = jnp.broadcast_to(jnp.asarray(tf, dtype), (nsys,))
+        pad = (-nsys) % ndev
+        if pad:
+            y0 = jnp.concatenate([y0, jnp.broadcast_to(y0[-1:], (pad, n))])
+            t0a = jnp.concatenate([t0a, jnp.full((pad,), t0a[-1], dtype)])
+            # tf = t0 -> padded systems are inactive from the first cond
+            tfa = jnp.concatenate([tfa, jnp.full((pad,), t0a[-1], dtype)])
+            if params is not None:
+                params = jax.tree_util.tree_map(
+                    lambda p: jnp.concatenate(
+                        [p, jnp.broadcast_to(p[-1:], (pad,) + p.shape[1:])]),
+                    params)
 
-    spec = P(axis)
+        spec = P(axis)
 
-    def body(y0_l, t0_l, tf_l, params_l):
-        if params is None:
-            f_l, jac_l = f, jac
-        else:
-            f_l = lambda t, y: f(t, y, params_l)
-            jac_l = lambda t, y: jac(t, y, params_l)
-        return ensemble_bdf_integrate(f_l, jac_l, y0_l, t0_l, tf_l, **kw)
+        def body(y0_l, t0_l, tf_l, params_l):
+            def local(fn):
+                if fn is None or params is None:
+                    return fn
+                return lambda t, y: fn(t, y, params_l)
 
-    stats_spec = EnsembleStats(*([spec] * len(EnsembleStats._fields)))
-    params_spec = jax.tree_util.tree_map(lambda _: spec, params)
-    fn = jax.shard_map(body, mesh=mesh,
-                       in_specs=(spec, spec, spec, params_spec),
-                       out_specs=(spec, stats_spec), check_vma=False)
-    y, st = fn(y0, t0a, tfa, params)
-    if st.nli is not None:
-        # each shard broadcast its own local Krylov total over its slice;
-        # restore the documented invariant (every entry == the GLOBAL
-        # total) by summing one representative entry per shard
+            return ensemble_bdf_integrate(
+                local(f), local(jac), y0_l, t0_l, tf_l,
+                f_soa=local(f_soa), jac_soa=local(jac_soa), **kw)
+
+        stats_spec = EnsembleStats(*([spec] * len(EnsembleStats._fields)))
+        params_spec = jax.tree_util.tree_map(lambda _: spec, params)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(spec, spec, spec, params_spec),
+                           out_specs=(spec, stats_spec), check_vma=False)
+        y, st = fn(y0, t0a, tfa, params)
         shard = y0.shape[0] // ndev
-        st = st._replace(nli=jnp.broadcast_to(jnp.sum(st.nli[::shard]),
-                                              st.nli.shape))
-    # trips, newton_trips and setup_trips stay per shard: each device
-    # runs its own loops
-    if st.npsolves is not None:
-        shard = y0.shape[0] // ndev
-        st = st._replace(npsolves=jnp.broadcast_to(
-            jnp.sum(st.npsolves[::shard]), st.npsolves.shape))
-    if pad:
-        y = y[:nsys]
-        st = jax.tree_util.tree_map(lambda s: s[:nsys], st)
+        # each shard broadcast its own local Krylov and psolve totals over
+        # its slice; restore the documented invariant (every entry == the
+        # GLOBAL total) by summing one representative entry per shard.
+        # trips, newton_trips and setup_trips stay per shard: each device
+        # runs its own loops
+        if st.nli is not None:
+            st = st._replace(nli=jnp.broadcast_to(jnp.sum(st.nli[::shard]),
+                                                  st.nli.shape))
+        if st.npsolves is not None:
+            st = st._replace(npsolves=jnp.broadcast_to(
+                jnp.sum(st.npsolves[::shard]), st.npsolves.shape))
+        if pad:
+            y = y[:nsys]
+            st = jax.tree_util.tree_map(lambda s: s[:nsys], st)
     return y, st

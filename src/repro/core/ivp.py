@@ -41,6 +41,7 @@ pins trajectory equality to 1e-12.
 """
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Any, Callable, NamedTuple, Optional
 
@@ -96,6 +97,11 @@ class IVP:
           persistent Newton storage drops from O(n^2) to O(nnz) per
           system and sparse kernels replace the dense sweeps.
     y0  : initial state pytree (``(nsys, n)`` for ensemble methods)
+    params : optional pytree of per-system data (arrays with leading axis
+          nsys for ensemble methods).  When set, ``f``, ``fe``, ``fi``,
+          ``jac``, ``f_soa`` and ``jac_soa`` are called as ``(t, y,
+          params)``; ``integrate(..., mesh=)`` hands each device its
+          slice, the one route for per-system data onto a mesh.
     """
 
     f: Optional[Callable] = None
@@ -106,6 +112,7 @@ class IVP:
     jac_soa: Optional[Callable] = None
     jac_sparsity: Optional[Any] = None
     y0: Pytree = None
+    params: Pytree = None
 
     def __post_init__(self):
         if (self.f is None) == (self.fe is None and self.fi is None):
@@ -124,8 +131,8 @@ class IVP:
         if self.f is not None:
             return self.f
         fe, fi = self.fe, self.fi
-        return lambda t, y: jax.tree_util.tree_map(
-            jnp.add, fe(t, y), fi(t, y))
+        return lambda t, y, *params: jax.tree_util.tree_map(
+            jnp.add, fe(t, y, *params), fi(t, y, *params))
 
 
 class Solution(NamedTuple):
@@ -194,6 +201,20 @@ def _need(problem: IVP, attr: str, method: str):
     return v
 
 
+def _bind_params(problem: IVP) -> IVP:
+    """``problem`` with its ``params`` bound into every callable (the
+    one-device path; on a mesh each device binds its own slice)."""
+    p = problem.params
+
+    def bind(fn):
+        return None if fn is None else (lambda t, y: fn(t, y, p))
+
+    return dataclasses.replace(
+        problem, params=None,
+        **{k: bind(getattr(problem, k))
+           for k in ("f", "fe", "fi", "jac", "f_soa", "jac_soa")})
+
+
 #: families that accept the step-telemetry ring (the implicit adaptive
 #: loops whose per-step behavior the SUNLogger analog records)
 _TELEMETRY_FAMILIES = ("bdf", "ensemble_dirk", "ensemble_bdf")
@@ -207,7 +228,8 @@ def integrate(problem: IVP, t0, tf, method: str = "bdf", *,
               opts: Optional[ODEOptions] = None,
               lin_solver=None, nonlin_solver=None,
               order: int = 5, live=None,
-              timed: Optional[bool] = None, **method_kw) -> Solution:
+              timed: Optional[bool] = None, mesh=None,
+              **method_kw) -> Solution:
     """Integrate ``problem`` from t0 to tf with ``method``.
 
     ctx           : :class:`~repro.core.context.Context`; a private one
@@ -236,6 +258,15 @@ def integrate(problem: IVP, t0, tf, method: str = "bdf", *,
                     path populates, so profiler regions and timings
                     agree.  Defaults to ``ctx.observability.profile``;
                     falls back to the untimed path under an outer trace.
+    mesh          : a :class:`jax.sharding.Mesh` with a ``'systems'``
+                    axis (``ensemble_bdf`` only): the systems, and
+                    ``IVP.params``, are split over its devices and each
+                    device integrates its own with no collectives
+                    (:func:`~repro.core.batched.
+                    ensemble_bdf_integrate_sharded`).  The Solution is
+                    per lane over all systems, as on one device;
+                    ``workspace_bytes`` is one device's.  ``telemetry``
+                    and ``session=``/``return_session=`` are refused.
     method_kw     : passed through to the underlying integrator
                     (``dense_jac``, ``msbp``, ``m_aa``, ...;
                     ``ensemble_bdf`` additionally takes ``session=`` /
@@ -272,6 +303,15 @@ def integrate(problem: IVP, t0, tf, method: str = "bdf", *,
             f"{', '.join(_TELEMETRY_FAMILIES)})")
     if tel_cap is None and obs.telemetry and fam in _TELEMETRY_FAMILIES:
         tel_cap = obs.telemetry_capacity
+    if mesh is not None and fam != "ensemble_bdf":
+        raise ValueError(f"method {method!r} takes no mesh= (the sharded "
+                         "system axis is an ensemble_bdf feature)")
+    if mesh is not None and tel_cap is not None:
+        raise ValueError("integrate(..., mesh=) takes no step telemetry "
+                         "(telemetry= or the context's observability "
+                         "telemetry): the ring is not sharded")
+    if problem.params is not None and mesh is None:
+        problem = _bind_params(problem)
     if live is not None and not fam.startswith("ensemble"):
         raise ValueError(f"method {method!r} takes no live= mask (dead-"
                          "lane masking applies to ensemble bundles only)")
@@ -364,13 +404,19 @@ def integrate(problem: IVP, t0, tf, method: str = "bdf", *,
         else:  # ensemble_bdf (families validated above)
             f = _need(problem, "f", method)
             jac = _need(problem, "jac", method)
-            out = list(batched.ensemble_bdf_integrate(
-                f, jac, problem.y0, t0, tf, order=order, opts=opts,
-                policy=opts.policy, linear_solver=lin_solver,
-                jac_sparsity=problem.jac_sparsity, mem=mem,
-                f_soa=problem.f_soa, jac_soa=problem.jac_soa,
-                return_session=return_session, telemetry=tel_cap,
-                **method_kw))
+            kw = dict(order=order, opts=opts, policy=opts.policy,
+                      linear_solver=lin_solver,
+                      jac_sparsity=problem.jac_sparsity, mem=mem,
+                      f_soa=problem.f_soa, jac_soa=problem.jac_soa,
+                      return_session=return_session, telemetry=tel_cap,
+                      **method_kw)
+            if mesh is None:
+                out = list(batched.ensemble_bdf_integrate(
+                    f, jac, problem.y0, t0, tf, **kw))
+            else:
+                out = list(batched.ensemble_bdf_integrate_sharded(
+                    f, jac, problem.y0, t0, tf, params=problem.params,
+                    mesh=mesh, **kw))
             if tel_cap is not None:
                 ring = out.pop()
             if return_session:
@@ -386,7 +432,8 @@ def integrate(problem: IVP, t0, tf, method: str = "bdf", *,
     profile_on = obs.profile if timed is None else bool(timed)
     if profile_on and any(
             isinstance(leaf, jax.core.Tracer) for leaf in
-            jax.tree_util.tree_leaves((problem.y0, t0, tf, method_kw))):
+            jax.tree_util.tree_leaves((problem.y0, problem.params, t0, tf,
+                                       method_kw))):
         profile_on = False
     timings = None
     if profile_on:

@@ -297,3 +297,227 @@ def test_bdf_sharded_matches_single_device():
                          text=True, env=env, timeout=900)
     assert out.returncode == 0, out.stdout + "\n" + out.stderr
     assert "OK" in out.stdout
+
+
+# ---------------------------------------------------------------------------
+# integrate(..., mesh=): the four-device deployment through the front-end,
+# with native SoA right-hand sides and per-system data in IVP.params.  One
+# subprocess (its own fake-device XLA flags) runs every sharded case and
+# its one-device twin; the tests below read what it saved.
+# ---------------------------------------------------------------------------
+
+MESH_NSYS = 1030          # not a multiple of 4: the last shard is padded
+MESH_SHARD = 258          # (1030 + 2) / 4 lanes a device
+MESH_RTOL, MESH_ATOL = 1e-4, 1e-8
+MESH_TF = 40.0
+
+_MESH_SCRIPT = textwrap.dedent("""
+    import sys
+    import jax
+    jax.config.update("jax_enable_x64", True)
+    import jax.numpy as jnp, numpy as np
+    from repro.core import problems
+    from repro.core.arkode import ODEOptions
+    from repro.core.ivp import IVP, integrate
+    from repro.core.linsol import BlockDiagGJ
+    from repro.core.policies import ExecPolicy
+    from repro.launch.mesh import make_ensemble_mesh
+
+    out_path, nsys, rtol, atol, tf = (sys.argv[1], int(sys.argv[2]),
+                                      *map(float, sys.argv[3:6]))
+    mesh = make_ensemble_mesh()
+    assert mesh.devices.size == 4
+    f, jac, f_soa, jac_soa = problems.robertson_family()
+    calls = []
+
+    def spy(fn, tag):
+        def g(t, y, p):
+            calls.append((tag, tuple(y.shape), tuple(p["k3"].shape)))
+            return fn(t, y, p)
+        return g
+
+    family = [spy(f, "f"), spy(jac, "jac"), spy(f_soa, "f_soa"),
+              spy(jac_soa, "jac_soa")]
+    rng = np.random.default_rng(1030)
+    spread = {"k1": np.full(nsys, 0.04), "k2": np.full(nsys, 1e4),
+              # k3 log-uniform over two decades around cvRoberts_dns's
+              "k3": 3e6 * 10.0 ** rng.uniform(0.0, 2.0, nsys)}
+    same = {"k1": np.full(nsys, 0.04), "k2": np.full(nsys, 1e4),
+            "k3": np.full(nsys, 3e7)}
+    y0 = jnp.zeros((nsys, 3)).at[:, 0].set(1.0)
+    out = {"k3": spread["k3"]}
+
+    def run(backend, params, on_mesh, **kw):
+        pol = ExecPolicy(backend=backend, interpret=True)
+        prob = IVP(**dict(zip(("f", "jac", "f_soa", "jac_soa"), family)),
+                   y0=y0,
+                   params={k: jnp.asarray(v) for k, v in params.items()})
+        return integrate(prob, 0.0, tf, "ensemble_bdf",
+                         opts=ODEOptions(rtol=rtol, atol=atol, policy=pol),
+                         lin_solver=BlockDiagGJ(),
+                         mesh=mesh if on_mesh else None, **kw)
+
+    for backend in ("jnp", "pallas"):
+        del calls[:]
+        sh = run(backend, spread, True)
+        out[f"{backend}_calls"] = np.asarray(sorted(set(calls)), object)
+        one = run(backend, spread, False)
+        out[f"{backend}_y"] = np.asarray(sh.y)
+        out[f"{backend}_y1"] = np.asarray(one.y)
+        for name in ("retcodes", "ok", "nsetups"):
+            out[f"{backend}_{name}"] = np.asarray(getattr(sh, name))
+        out[f"{backend}_trips"] = np.asarray(sh.stats.trips)
+        out[f"{backend}_success"] = np.asarray(sh.success)
+    live = np.arange(nsys) % 3 != 0
+    sh = run("pallas", same, True, live=jnp.asarray(live))
+    one = run("pallas", same, False)
+    out["same_y"], out["same_y1"] = np.asarray(sh.y), np.asarray(one.y)
+    out["same_nni"] = np.asarray(sh.nni)
+    out["same_nni_lane"] = np.asarray(one.stats.nni)
+    out["same_live"] = live
+    np.savez(out_path, **out)
+    print("OK")
+""")
+
+
+@pytest.fixture(scope="module")
+def mesh_run(tmp_path_factory):
+    """Every sharded case of ``integrate(..., mesh=)`` on four CPU
+    devices and its one-device twin, as saved arrays."""
+    path = tmp_path_factory.mktemp("mesh") / "mesh.npz"
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"      # children never touch the chip
+    out = subprocess.run(
+        [sys.executable, "-c", _MESH_SCRIPT, str(path), str(MESH_NSYS),
+         str(MESH_RTOL), str(MESH_ATOL), str(MESH_TF)],
+        capture_output=True, text=True, env=env, timeout=900)
+    assert out.returncode == 0, out.stdout + "\n" + out.stderr
+    return dict(np.load(path, allow_pickle=True))
+
+
+def _robertson_reference(k3):
+    """y(tf) of every lane by the benchmark's plain float64 reference
+    (NumPy; imports nothing of the system under test)."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "robertson_reference",
+        os.path.join(REPO, "chipbench", "references", "robertson.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    m = len(k3)
+    y, done = mod.integrate(np.tile([1.0, 0.0, 0.0], (m, 1)),
+                            np.full(m, 0.04), np.full(m, 1e4), k3, MESH_TF,
+                            rtol=1e-10, atol=1e-16)
+    assert done.all()
+    return y
+
+
+@pytest.mark.slow
+def test_mesh_matches_float64_reference(mesh_run):
+    """Every lane of the sharded run, padded shard included, against the
+    plain float64 reference, in units of rtol |y| + atol.  The BDF error
+    test bounds each step's local error by one unit; the global error
+    at tf is a small multiple of it, as in the benchmark's one-chip cell,
+    whose ``correct`` limit on the same number is 12.  Here the worst
+    lane reads 1.95 on both backends, while lanes handed their
+    neighbours' rates (k3 rolled by one lane) land 18,900 units off."""
+    y_ref = _robertson_reference(mesh_run["k3"])
+    for backend in ("jnp", "pallas"):
+        y = mesh_run[f"{backend}_y"]
+        assert y.shape == (MESH_NSYS, 3)
+        assert (mesh_run[f"{backend}_retcodes"] == 0).all()
+        assert mesh_run[f"{backend}_ok"].all()
+        assert bool(mesh_run[f"{backend}_success"])
+        err = np.abs(y - y_ref) / (MESH_RTOL * np.abs(y_ref) + MESH_ATOL)
+        assert err.max() < 12.0, (backend, err.max())
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_mesh_matches_one_device(mesh_run, backend):
+    """Sharded against one-device ``integrate()`` on the same systems,
+    in the tolerance form of the backend-parity tests
+    (:func:`test_bdf_per_lane_tf_clip_rescale_parity`, C = 10); every
+    per-lane output covers all nsys lanes, and each shard ran its own
+    step loop."""
+    y, y1 = mesh_run[f"{backend}_y"], mesh_run[f"{backend}_y1"]
+    np.testing.assert_allclose(y, y1, rtol=10 * MESH_RTOL,
+                               atol=10 * MESH_ATOL)
+    for name in ("retcodes", "ok", "nsetups", "trips"):
+        assert mesh_run[f"{backend}_{name}"].shape == (MESH_NSYS,)
+    trips = mesh_run[f"{backend}_trips"]
+    per_shard = [np.unique(trips[i:i + MESH_SHARD])
+                 for i in range(0, MESH_NSYS, MESH_SHARD)]
+    assert all(len(u) == 1 for u in per_shard)
+    # the lanes differ, so the devices' loops ran different trip counts
+    assert len({int(u[0]) for u in per_shard}) > 1
+
+
+@pytest.mark.slow
+def test_mesh_one_system_is_bitwise(mesh_run):
+    """All lanes one system (cvRoberts_dns's rates): every lane of the
+    sharded run equals every other bit for bit, and equals the
+    one-device run; ``live=`` masks the stats on a mesh as on one
+    device."""
+    y, y1 = mesh_run["same_y"], mesh_run["same_y1"]
+    assert np.array_equal(y, np.broadcast_to(y[0], y.shape))
+    assert np.array_equal(y, y1)
+    live = mesh_run["same_live"]
+    lane = mesh_run["same_nni_lane"]
+    assert len(np.unique(lane)) == 1
+    assert int(mesh_run["same_nni"]) == int(lane[0]) * int(live.sum())
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_mesh_calls_native_soa_on_shards(mesh_run, backend):
+    """Each device evaluates the native SoA forms on its own (3, nsys/4)
+    slice with its own (nsys/4,) slice of ``params``; the AoS forms, and
+    with them the transposing wrapper, are never traced."""
+    calls = {tuple(c) for c in mesh_run[f"{backend}_calls"]}
+    assert calls == {("f_soa", (3, MESH_SHARD), (MESH_SHARD,)),
+                     ("jac_soa", (3, MESH_SHARD), (MESH_SHARD,))}
+
+
+def test_mesh_refusals():
+    """mesh= belongs to ensemble_bdf, and the step-telemetry ring is not
+    sharded."""
+    from repro.core import problems
+    from repro.core.ivp import IVP, integrate
+    from repro.launch.mesh import make_ensemble_mesh
+
+    f, jac, f_soa, jac_soa = problems.robertson_family()
+    p = {k: jnp.ones(4) for k in ("k1", "k2", "k3")}
+    prob = IVP(f=f, jac=jac, f_soa=f_soa, jac_soa=jac_soa,
+               y0=jnp.ones((4, 3)), params=p)
+    mesh = make_ensemble_mesh()
+    with pytest.raises(ValueError, match="mesh"):
+        integrate(prob, 0.0, 0.1, "ensemble_erk", mesh=mesh)
+    with pytest.raises(ValueError, match="telemetry"):
+        integrate(prob, 0.0, 0.1, "ensemble_bdf", mesh=mesh, telemetry=4)
+    with pytest.raises(ValueError, match="session"):
+        integrate(prob, 0.0, 0.1, "ensemble_bdf", mesh=mesh,
+                  return_session=True)
+
+
+def test_params_bind_on_one_device():
+    """Without a mesh, IVP.params is bound into the callables: the same
+    answer as closing over the rates."""
+    from repro.core import problems
+    from repro.core.ivp import IVP, integrate
+
+    f, jac, f_soa, jac_soa = problems.robertson_family()
+    nsys = 8
+    p = {"k1": jnp.full(nsys, 0.04), "k2": jnp.full(nsys, 1e4),
+         "k3": jnp.linspace(3e6, 3e8, nsys)}
+    y0 = jnp.zeros((nsys, 3)).at[:, 0].set(1.0)
+    via = integrate(IVP(f=f, jac=jac, f_soa=f_soa, jac_soa=jac_soa, y0=y0,
+                        params=p), 0.0, 1.0, "ensemble_bdf")
+    closed = integrate(IVP(f=lambda t, y: f(t, y, p),
+                           jac=lambda t, y: jac(t, y, p),
+                           f_soa=lambda t, y: f_soa(t, y, p),
+                           jac_soa=lambda t, y: jac_soa(t, y, p), y0=y0),
+                       0.0, 1.0, "ensemble_bdf")
+    assert np.array_equal(np.asarray(via.y), np.asarray(closed.y))

@@ -271,7 +271,12 @@ def test_ensemble_bdf_ops_carry_their_phase(one_chip):
     broadcasts XLA adds on its own; every fusion of the Newton loop
     names the Newton phase.  The device trace's ops are attributed to a
     phase by that name."""
-    text = _ensemble_bdf_text(one_chip, False, "pallas", nsys=1024)
+    _assert_phases(_ensemble_bdf_text(one_chip, False, "pallas", nsys=1024))
+
+
+def _assert_phases(text):
+    """The checks of :func:`test_ensemble_bdf_ops_carry_their_phase` on
+    one compiled program's text."""
     comps = _computations(text)
     step = _reachable(comps, _loop_body(comps, "jit(<lambda>)/"))
     newton = _reachable(comps, _loop_body(comps, "ensemble_bdf.newton/"))
@@ -297,6 +302,77 @@ def test_ensemble_bdf_ops_carry_their_phase(one_chip):
     print(f"{len(unscoped)} of {len(in_step)} step-loop instructions "
           f"unscoped (XLA's own copies, prefetches and broadcasts); "
           f"{sum(_phase(s) is None for _, s in run)} of {len(run)} in all")
+
+
+def _ensemble_mesh_text(topo, x64, nsys):
+    """Compiled program of the float32 Robertson ensemble through
+    ``integrate(..., mesh=)`` over the described v5e 2x2 (one shard of
+    ``nsys / 4`` systems a chip), with the native SoA family and the
+    rate constants in ``IVP.params``."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.core import problems
+    from repro.core.arkode import ODEOptions
+    from repro.core.ivp import IVP, integrate
+    from repro.core.linsol import BlockDiagGJ
+    from repro.core.policies import ExecPolicy
+
+    mesh = Mesh(np.asarray(topo.devices), ("systems",))
+    policy = ExecPolicy(backend="pallas", interpret=False, device="tpu_v5e")
+    f, jac, f_soa, jac_soa = problems.robertson_family()
+
+    def run(y0, params):
+        sol = integrate(
+            IVP(f=f, jac=jac, f_soa=f_soa, jac_soa=jac_soa, y0=y0,
+                params=params), 0.0, 40.0, "ensemble_bdf",
+            opts=ODEOptions(rtol=1e-4, atol=1e-8, policy=policy),
+            lin_solver=BlockDiagGJ(), mesh=mesh)
+        return sol.y, sol.retcodes
+
+    shard = NamedSharding(mesh, P("systems"))
+    with jax.enable_x64(x64):
+        y0 = jax.ShapeDtypeStruct((nsys, 3), jnp.float32, sharding=shard)
+        params = {k: jax.ShapeDtypeStruct((nsys,), jnp.float32,
+                                          sharding=shard)
+                  for k in ("k1", "k2", "k3")}
+        return jax.jit(run).lower(y0, params).compile().as_text()
+
+
+@X64
+def test_ensemble_mesh_compiles_with_kernels(topo, x64):
+    """The four-chip deployment (4,194,304 systems, 1,048,576 a chip)
+    through ``integrate(..., mesh=)``: the Pallas kernels run in each
+    chip's step loop, and every instruction outside the step loop, but
+    XLA's own copies, prefetches and broadcasts, carries the sharded
+    wrapper's scope ``ensemble_bdf.shards``."""
+    text = _ensemble_mesh_text(topo, x64, 4 * 1048576)
+    _assert_kernel(text)
+    assert text.count("tpu_custom_call") >= 5
+    comps = _computations(text)
+    body = _loop_body(comps, "jit(<lambda>)/")
+    step = _reachable(comps, body)
+    step |= {c for lines in comps.values() for s in lines
+             if _opcode(s) == "while"
+             and re.search(rf"body=%{re.escape(body)}\b", s)
+             for c in _reachable(
+                 comps, re.search(r"condition=%([\w.\-]+)", s).group(1))}
+    inner = {n for lines in comps.values() for s in lines
+             for n in re.findall(r"to_apply=%([\w.\-]+)", s)}
+    outside = [s for c in comps
+               if "fused_computation" not in c and c not in inner
+               and c not in step
+               for s in comps[c] if _opcode(s) not in _NOT_RUN]
+    assert outside
+    for s in outside:
+        m = _OP_NAME.search(s)
+        assert (m and "ensemble_bdf.shards" in m.group(1).split("/")) \
+            or _opcode(s) in _XLA_OWN, s[:120]
+
+
+def test_ensemble_mesh_ops_carry_their_phase(topo):
+    """The checks of :func:`test_ensemble_bdf_ops_carry_their_phase` on
+    the sharded program: inside each chip's step loop the phases are
+    the one-chip program's."""
+    _assert_phases(_ensemble_mesh_text(topo, False, 4 * 1024))
 
 
 def test_device_kind_table():
