@@ -52,6 +52,7 @@ from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from . import controller as ctrl
@@ -528,6 +529,42 @@ class _BdfCarry(NamedTuple):
     setup_trips: jnp.ndarray  # scalar: steps whose lsetup branch ran
 
 
+def _lane_rows(table, index, dtype):
+    """``table[index]`` (host table, per-lane ``index``) as one row per
+    column, each shaped like ``index``.  A select among the table's
+    constants: an (nsys, k) gather would put k on a TPU's lane axis,
+    padded to 128, and need a re-layout before every contraction."""
+    return [lax.select_n(index, *[jnp.full(index.shape, v, dtype)
+                                  for v in col]) for col in table.T]
+
+
+def _predict_soa(Z, q, nvalid):
+    """The BDF predictor and the corrector's history term of the
+    (QMAX+1, n, nsys) history ``Z``, per lane at order ``q`` with
+    ``nvalid`` valid entries:
+
+        y_pred = sum_j PREDP[min(nvalid, q), j] Z[j]
+        psi    = -sum_{j < QMAX} ALPHA[q - 1, j + 1] Z[j]
+
+    as unrolled elementwise multiply-adds in Z's own layout: float32
+    VPU arithmetic at the working precision, no transpose and no
+    matmul (which a TPU would round to bfloat16 by default).  Each sum
+    starts from Z[0] itself (its coefficient less one), so every add
+    takes exactly one product: a backend that fuses a multiply into
+    the add (XLA's CPU code) then fuses the same one in every lane,
+    and a lane's bits do not depend on the batch width."""
+    dtype = Z.dtype
+    head = np.eye(1, _cv.QMAX + 1)
+    pc = _lane_rows(_cv._PREDP_T - head, jnp.minimum(nvalid, q), dtype)
+    ps = _lane_rows(-_cv._ALPHA_T[:, 1:] - head[:, :-1], q - 1, dtype)
+    y_pred, psi = Z[0], Z[0]
+    for j in range(_cv.QMAX + 1):
+        y_pred = y_pred + pc[j] * Z[j]
+    for j in range(_cv.QMAX):
+        psi = psi + ps[j] * Z[j]
+    return y_pred, psi
+
+
 def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
                            t0, tf, *, order: int = 5,
                            opts: ODEOptions = ODEOptions(),
@@ -750,23 +787,9 @@ def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
             Z = dv.lagrange_rescale_soa(eta_clip, nvalid, c.Z,
                                         active & (eta_clip != one), policy)
         with jax.named_scope("ensemble_bdf.predict"):
-            qi = c.q - 1
-            alphas = jnp.asarray(_cv._ALPHA_T, dtype)[qi]  # (nsys, QMAX+1)
-            beta = jnp.asarray(_cv._BETA_T, dtype)[qi]     # (nsys,)
-            p_pred = jnp.minimum(nvalid, c.q)
-            pred_c = jnp.asarray(_cv._PREDP_T, dtype)[p_pred]
-            # predictor / psi: per-system coefficient contractions over
-            # the history, evaluated as the AoS einsum on transposed
-            # views so the jnp backend keeps the pre-SoA accumulation
-            # order bitwise (XLA folds the layout changes into the
-            # contraction).  O(Q*n*nsys) once per step — NOT per Newton
-            # iteration.  HIGHEST keeps a TPU from rounding the operands
-            # to bfloat16.
-            Zaos = jnp.transpose(Z, (2, 0, 1))       # (nsys, QMAX+1, n)
-            y_pred = jnp.einsum("sj,sjk->sk", pred_c, Zaos,
-                                precision=lax.Precision.HIGHEST).T
-            psi = (-jnp.einsum("sj,sjk->sk", alphas[:, 1:], Zaos[:, :-1],
-                               precision=lax.Precision.HIGHEST)).T
+            beta = jnp.asarray(_cv._BETA_T, dtype)[c.q - 1]  # (nsys,)
+            # O(Q*n*nsys) once per step — NOT per Newton iteration
+            y_pred, psi = _predict_soa(Z, c.q, nvalid)
             gamma = beta * hs                        # (nsys,)
             t_new = c.t + hs
             w = 1.0 / (opts.rtol * jnp.abs(Z[0]) + opts.atol)  # (n, nsys)

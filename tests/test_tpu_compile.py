@@ -304,6 +304,27 @@ def _assert_phases(text):
           f"{sum(_phase(s) is None for _, s in run)} of {len(run)} in all")
 
 
+def test_ensemble_bdf_predict_is_lane_dense(one_chip):
+    """The predictor's per-lane coefficients and contractions keep the
+    system axis minor: no instruction of the step loop under
+    ``ensemble_bdf.predict`` (fused bodies included) makes an
+    ``[nsys, k]`` array with k <= QMAX+1, whose k a TPU pads to 128
+    lanes: no per-lane coefficient gather, and no re-layout of one."""
+    from repro.core.cvode import QMAX
+    nsys = 1024
+    comps = _computations(_ensemble_bdf_text(one_chip, False, "pallas",
+                                             nsys=nsys))
+    step = _reachable(comps, _loop_body(comps, "jit(<lambda>)/"))
+    predict = [s for c in step for s in comps[c]
+               if _phase(s) == "ensemble_bdf.predict"]
+    assert predict
+    narrow = re.compile(rf"^\w+\[{nsys},(\d+)\]")
+    for s in predict:
+        m = narrow.match(s.split(" = ", 1)[1])
+        assert not (m and int(m.group(1)) <= QMAX + 1), \
+            (_opcode(s), s[:120])
+
+
 def _ensemble_mesh_text(topo, x64, nsys):
     """Compiled program of the float32 Robertson ensemble through
     ``integrate(..., mesh=)`` over the described v5e 2x2 (one shard of
