@@ -17,7 +17,7 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 
 from repro.apps import brusselator as br
-from repro.configs.brusselator import BrusselatorConfig
+from repro.apps.brusselator import BrusselatorConfig
 from repro.core.policies import ExecPolicy, XLA_FUSED
 
 

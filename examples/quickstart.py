@@ -1,29 +1,18 @@
-"""Quickstart: the two faces of `repro` in one script.
-
-1. SUNDIALS-on-JAX: solve a stiff ODE through the unified front-end
-   (`IVP` + `integrate(method=...)` -> `Solution`), swapping integration
-   method and linear solver without touching the problem.
-2. LM framework: train a small transformer for a few steps with AdamW,
-   then with the gradient-flow (ODE) optimizer — the same integrator
-   driving a parameter pytree.
+"""Quickstart: solve a stiff ODE through the unified front-end
+(`IVP` + `integrate(method=...)` -> `Solution`), choosing the
+integration method and linear solver without touching the problem.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
-import jax
 import jax.numpy as jnp
 
-from repro import configs
 from repro.core.context import Context
 from repro.core.ivp import IVP, integrate
 from repro.core.linsol import DenseGJ
-from repro.data import pipeline
-from repro.models import Model
-from repro.optim import adamw, gradflow
-from repro.train import step as tstep
 
 
 def ode_demo():
-    print("=== 1. stiff ODE via the unified front-end (CVODE analog) ===")
+    print("=== stiff ODE via the unified front-end (CVODE analog) ===")
 
     def f(t, y):  # Robertson chemical kinetics
         return jnp.stack([
@@ -44,27 +33,5 @@ def ode_demo():
           f"workspace={sol.workspace_bytes}B")
 
 
-def lm_demo():
-    print("=== 2. LM training (AdamW, then gradient-flow ODE optimizer) ===")
-    cfg = configs.get("internlm2-1.8b-smoke")
-    model = Model(cfg)
-    state = tstep.init_state(model, jax.random.PRNGKey(0))
-    dcfg = pipeline.DataConfig(vocab_size=cfg.vocab_size, seq_len=64,
-                               global_batch=8)
-    train = jax.jit(tstep.make_train_step(model))
-    for i, b in zip(range(5), pipeline.batches(dcfg)):
-        batch = {k: jnp.asarray(v) for k, v in b.items()}
-        state, m = train(state, batch)
-        print(f"  adamw step {i}: loss={float(m['loss']):.4f}")
-    batch = {k: jnp.asarray(v) for k, v in next(pipeline.batches(dcfg, 5)).items()}
-    lf = lambda p: model.loss(p, batch)
-    before = float(lf(state.params))
-    p2, st = gradflow.step(lf, state.params,
-                           gradflow.GradFlowConfig(tau=0.2, max_steps=8))
-    print(f"  gradflow: {int(st.steps)} adaptive ODE steps, "
-          f"loss {before:.4f} -> {float(lf(p2)):.4f}")
-
-
 if __name__ == "__main__":
     ode_demo()
-    lm_demo()

@@ -27,6 +27,7 @@ analog of the paper's GPU-GPU NVLink point-to-point transfers.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable, Optional
 
 import jax
@@ -35,7 +36,24 @@ import jax.numpy as jnp
 from repro.core import arkode, butcher, direct, krylov, matrix
 from repro.core.arkode import ODEOptions
 from repro.core.policies import ExecPolicy, XLA_FUSED
-from repro.configs.brusselator import BrusselatorConfig
+
+
+@dataclasses.dataclass(frozen=True)
+class BrusselatorConfig:
+    """Problem and solver settings of the paper's brusselator (§7)."""
+
+    name: str = "brusselator1d"
+    nx: int = 512            # mesh points (paper: up to 1.536e8)
+    c: float = 0.01          # advection speed
+    A: float = 1.0
+    B: float = 3.5
+    eps: float = 5e-6        # stiffness parameter
+    b_domain: float = 10.0   # domain size (paper b in {10..2560})
+    t_final: float = 10.0
+    alpha: float = 0.1       # initial-bump amplitude
+    rtol: float = 1e-6
+    atol: float = 1e-9
+    solver: str = "task-local"   # 'task-local' | 'global'
 
 
 def initial_state(cfg: BrusselatorConfig) -> jnp.ndarray:

@@ -1,10 +1,15 @@
 """Persisted autotune cache + the ``backend='auto'`` resolver.
 
-The measured half of cost-model-driven dispatch: ``benchmarks/run.py
---tune`` times every OP_TABLE op over a grid of signatures on both
-backends and writes the winners to ``.autotune/<device>.json``
-(committed alongside the BENCH files).  ``backend='auto'`` dispatch
-resolves each call site at trace time:
+The measured half of cost-model-driven dispatch: :func:`tune` times
+every OP_TABLE op over a grid of signatures on both backends and writes
+the winners to ``.autotune/<device>.json`` (committed with the code)::
+
+    PYTHONPATH=src python -m repro.core.autotune
+
+``<device>`` is the roofline device the program runs on: ``interpret``
+on a CPU, where Pallas kernels run in interpret mode, or the chip's row
+(``tpu_v5e``) on a TPU.  ``backend='auto'`` dispatch resolves each call
+site at trace time:
 
 1. per-op override on the policy (``ExecPolicy.op_overrides``) — forced;
 2. exact autotune-cache hit for (op, shape-signature, dtype) — the
@@ -27,12 +32,19 @@ import dataclasses
 import json
 import math
 import os
+import time
 from pathlib import Path
 from typing import Dict, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from repro.analysis import opcost
 from repro.analysis.opcost import OpSig
 from repro.analysis.roofline import get_device
+
+from .policies import ExecPolicy, XLA_FUSED
 
 #: bump when the key derivation or entry layout changes; mismatched
 #: files are discarded wholesale (stale winners are worse than a cold
@@ -122,7 +134,7 @@ class AutotuneCache:
         payload = {"schema": SCHEMA_VERSION, "device": self.device,
                    "note": ("measured best-of-reps seconds per backend; "
                             "regenerate with: PYTHONPATH=src python -m "
-                            "benchmarks.run --tune"),
+                            "repro.core.autotune"),
                    "entries": {k: e.to_json()
                                for k, e in sorted(self.entries.items())}}
         self.path.write_text(json.dumps(payload, indent=2) + "\n")
@@ -308,3 +320,177 @@ def resolve(op: str, policy, *args):
 def decisions_report(policy) -> dict:
     """Report for the resolver belonging to ``policy``'s device."""
     return get_resolver(policy.device_name()).report()
+
+
+# ---------------------------------------------------------------------------
+# The sweep that fills the cache.  Every OP_TABLE op is timed on both
+# dispatch backends (best-of-reps MIN) over a grid of shape signatures,
+# the pallas side over a couple of tile candidates as well.
+# ---------------------------------------------------------------------------
+
+STREAM_N = (4096, 262144)
+GJ_NSYS = (512, 4096, 32768)
+SOA_NSYS = (512, 4096, 32768)
+
+
+def _time(fn, reps=3):
+    """Best-of-reps wall time (MIN), each rep synced: the minimum is the
+    statistic least disturbed by transient host load."""
+    jax.block_until_ready(fn())
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn())
+        best = min(best, time.perf_counter() - t0)
+    return best
+
+
+def _pallas_policy(op: str, tile: int) -> ExecPolicy:
+    kw = {"backend": "pallas"}
+    if op in opcost.BATCHED_OPS:
+        kw["batch_tile"] = tile
+    elif op in opcost.REDUCTION_OPS:
+        kw["reduce_tile"] = tile
+    else:
+        kw["block_elems"] = tile
+    return ExecPolicy(**kw)
+
+
+def _tiles_for(op: str, axis_len: int):
+    top = opcost._lane_ceil(axis_len)
+    if op in opcost.BATCHED_OPS:
+        cands = {min(512, top), top}
+    else:
+        cands = {min(8 * 128, top), min(top, 1 << 16)}
+    return sorted(cands)
+
+
+def _cases():
+    """Yield (op, args) covering every OP_TABLE op over the shape grid.
+    ``args`` are the public-wrapper positional arguments — the same
+    tuple opcost.signature consumes, so tuner keys and auto-dispatch
+    keys agree by construction."""
+    from .sunmatrix import SparseCSR
+
+    def rnd(i, shape):
+        return jax.random.normal(jax.random.PRNGKey(i), shape)
+
+    for n in STREAM_N:
+        x, y, z = rnd(1, (n,)), rnd(2, (n,)), rnd(3, (n,))
+        w = jnp.abs(y) + 0.1
+        m = (x > 0).astype(x.dtype)
+        coeffs = [0.3, -1.2, 2.5]
+        yield "linear_sum", (2.0, x, -0.5, y)
+        yield "linear_combination", (coeffs, [x, y, z])
+        yield "scale_add_multi", (coeffs, x, [x, y, z])
+        yield "axpy", (1.7, x, y)
+        yield "dot", (x, y)
+        yield "wrms_norm", (x, w)
+        yield "wrms_norm_mask", (x, w, m)
+        yield "dot_prod_multi", (x, [y, z, w])
+        yield "wrms_ss", (x, w)
+    for b in (3, 8, 16, 24):
+        for nsys in GJ_NSYS:
+            A = rnd(b, (b, b, nsys)) * 0.05
+            A = jnp.eye(b)[:, :, None] - A        # diagonally dominant
+            r = rnd(b + 1, (b, nsys))
+            yield "block_solve_soa", (A, r)
+            if b <= 16 and nsys <= 4096:
+                yield "block_inverse_soa", (A,)
+            if b <= 8 and nsys <= 4096:
+                yield "blockdiag_spmv_soa", (A, r)
+    for n in (3, 8):
+        for nsys in SOA_NSYS:
+            zz, ff, psi = rnd(20, (n, nsys)), rnd(21, (n, nsys)), \
+                rnd(22, (n, nsys))
+            gmb = jnp.abs(rnd(23, (nsys,))) + 0.1
+            ww = jnp.abs(rnd(24, (n, nsys))) + 0.1
+            mb = rnd(25, (nsys,)) > 0.3
+            yield "newton_residual_soa", (zz, ff, psi, gmb, True)
+            if nsys >= 4096:
+                yield "masked_update_wrms_soa", (zz, ff, ww, mb)
+                yield "wrms_soa", (zz, ww)
+            if nsys == 4096:
+                q1 = 6
+                eh = jnp.exp(0.5 * rnd(26, (nsys,)))
+                qh = jnp.full((nsys,), q1 - 1, jnp.int32)
+                Zh = rnd(27, (q1, n, nsys))
+                yield "lagrange_rescale_soa", (eh, qh, Zh, mb)
+    # sparse: banded CSR + a small shared-pattern BSR ensemble
+    for ncsr in (133, 1024):
+        band = np.abs(np.arange(ncsr)[:, None] - np.arange(ncsr)) <= 2
+        dense = np.asarray(rnd(30, (ncsr, ncsr))) * band
+        csr = SparseCSR.from_dense(dense)
+        xs = rnd(31, (ncsr,))
+        yield "csr_spmv", (csr.data, xs, csr.pattern)
+    nblk, bb = 5, 3
+    brows, bcols = zip(*[(i, j) for i in range(nblk)
+                         for j in range(nblk) if abs(i - j) <= 1])
+    bpat = (tuple(brows), tuple(bcols), nblk)
+    for nsys in (512, 4096):
+        Vb = rnd(32, (len(brows), bb, bb, nsys)) + \
+            jnp.where((jnp.asarray(brows) == jnp.asarray(bcols))
+                      [:, None, None, None],
+                      (bb + 2.0) * jnp.eye(bb)[None, :, :, None], 0.0)
+        xb = rnd(33, (nblk, bb, nsys))
+        yield "bsr_spmv_soa", (Vb, xb, bpat)
+        yield "bsr_block_jacobi_inverse_soa", (Vb, bpat)
+
+
+def _wrapper(op):
+    """The public dispatch wrapper for ``op`` with (args..., policy)."""
+    from . import dispatch as dp
+    fns = {
+        "newton_residual_soa": lambda z, f, p, g, neg, pol:
+            dp.newton_residual_soa(z, f, p, g, pol, negate=neg),
+        "masked_update_wrms_soa": lambda z, dz, w, m, pol:
+            jnp.concatenate([a.ravel() for a in
+                             dp.masked_update_wrms_soa(z, dz, w, m, pol)]),
+        "scale_add_multi": lambda c, x, ys, pol:
+            jnp.stack(dp.scale_add_multi(c, x, ys, pol)),
+    }
+    if op in fns:
+        return fns[op]
+    return lambda *a: getattr(dp, op)(*a)
+
+
+def _measure_case(op, args, reps=3):
+    """(sig, t_jnp, t_pallas_best, best_tile) for one (op, args)."""
+    call = _wrapper(op)
+    sig = opcost.signature(op, args)
+    t_jnp = _time(lambda: call(*args, XLA_FUSED), reps=reps)
+    best_t, best_tile = float("inf"), 0
+    for tile in _tiles_for(op, sig.axis_len):
+        t = _time(lambda: call(*args, _pallas_policy(op, tile)), reps=reps)
+        if t < best_t:
+            best_t, best_tile = t, tile
+    return sig, t_jnp, best_t, best_tile
+
+
+def tune(reps: int = 3, verbose: bool = True) -> AutotuneCache:
+    """Measure the full grid on the device this program runs on and
+    (re)write its ``.autotune/<device>.json``.  Returns the cache."""
+    device = ExecPolicy(backend="pallas").device_name()
+    cache = AutotuneCache(device)
+    for op, args in _cases():
+        sig, t_jnp, t_pal, tile = _measure_case(op, args, reps=reps)
+        entry = Entry(sig=sig, t_jnp=t_jnp, t_pallas=t_pal, tile=tile)
+        cache.put(entry)
+        if verbose:
+            print(f"tune.{sig.key()},{entry.winner},"
+                  f"jnp_us={t_jnp * 1e6:.0f},pallas_us={t_pal * 1e6:.0f},"
+                  f"tile={tile}", flush=True)
+    path = cache.save()
+    audit = model_audit(cache)
+    if verbose:
+        print(f"tune.saved,{len(cache.entries)},{path}", flush=True)
+        print(f"tune.model_agreement,"
+              f"{audit['model_agree']}/{audit['model_total']},"
+              f"{audit['model_agreement']:.2f}", flush=True)
+    reset_resolver(device)                # pick up the fresh cache
+    return cache
+
+
+if __name__ == "__main__":
+    jax.config.update("jax_enable_x64", True)
+    tune()

@@ -1060,6 +1060,18 @@ def ensemble_bdf_integrate(f: Callable, jac: Callable, y0: jnp.ndarray,
     return tuple(out)
 
 
+def make_ensemble_mesh(n_devices: int = 0):
+    """1-D ('systems',) mesh for the ensemble subsystem: the batch of
+    independent ODE systems is sharded across all (or the first
+    ``n_devices``) local devices; each device advances its shard with no
+    collectives (the paper's one-integrator-per-stream bundles)."""
+    from jax.sharding import Mesh
+    devs = jax.devices()
+    if n_devices:
+        devs = devs[:n_devices]
+    return Mesh(np.asarray(devs), ("systems",))
+
+
 def ensemble_bdf_integrate_sharded(f: Callable, jac: Callable,
                                    y0: jnp.ndarray, t0, tf, *,
                                    params=None, mesh=None,
@@ -1089,7 +1101,7 @@ def ensemble_bdf_integrate_sharded(f: Callable, jac: Callable,
              Without them the AoS forms are wrapped with a transpose
              inside each device's loop.
     mesh   : a 1-D ('systems',) mesh by default
-             (:func:`repro.launch.mesh.make_ensemble_mesh`).
+             (:func:`make_ensemble_mesh`).
     If nsys is not a multiple of the device count the batch is padded
     with finished dummy systems (tf = t0: masked no-ops from step one).
     The whole call runs under ``jax.named_scope("ensemble_bdf.shards")``:
@@ -1099,7 +1111,6 @@ def ensemble_bdf_integrate_sharded(f: Callable, jac: Callable,
     ``session=``/``return_session=`` and ``telemetry=`` are refused.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import make_ensemble_mesh
 
     if kw.pop("session", None) is not None or kw.pop("return_session",
                                                     False):

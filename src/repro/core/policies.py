@@ -64,8 +64,8 @@ at trace time the dispatch layer extracts the op's shape signature —
 (n, nsys, b, K, nnz, dtype) — and resolves it through
 :mod:`repro.core.autotune`: a measured entry in the persisted autotune
 cache (``.autotune/<device>.json``, regenerated by
-``PYTHONPATH=src python -m benchmarks.run --tune`` and committed like
-the BENCH files) wins outright; otherwise the analytical roofline model
+``PYTHONPATH=src python -m repro.core.autotune`` and committed with the
+code) wins outright; otherwise the analytical roofline model
 (:mod:`repro.analysis.opcost`, over the
 :data:`repro.analysis.roofline.DEVICES` table) predicts the winner and
 a VMEM-feasible tile.  Per-op pins go in ``op_overrides``; resolved
@@ -82,9 +82,7 @@ from typing import Optional, Tuple
 class ExecPolicy:
     """Execution policy for vector/matrix/solver operations.
 
-    backend       : 'jnp'    — plain jnp ops, XLA fuses (default; used by
-                                the dry-run path since XLA:CPU cannot
-                                lower TPU pallas_call);
+    backend       : 'jnp'    — plain jnp ops, XLA fuses (default);
                     'pallas' — hand-written kernels from repro.kernels;
                     'auto'   — per-call-site choice from the autotune
                                 cache / analytical cost model (see the

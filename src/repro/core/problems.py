@@ -2,9 +2,9 @@
 
 The batched Robertson kinetics problem is the canonical driver of the
 ensemble subsystem: the example (``examples/batched_kinetics.py``), the
-benchmark (``benchmarks/ensemble_bench.py``) and the test suite all
-integrate the SAME problem, so it lives here once instead of as copies
-that could drift apart.
+chip benchmark (``chipbench/``) and the test suite all integrate the
+SAME problem, so it lives here once instead of as copies that could
+drift apart.
 """
 from __future__ import annotations
 

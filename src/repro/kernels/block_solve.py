@@ -29,7 +29,7 @@ Two elimination kernels, selected by block size:
   shape while they all fit in vector registers;
 * ``b > UNROLL_MAX_B``  — a **row-tiled** elimination: the b^2 live
   vectors of the unrolled form spill registers at b=16 (256 vectors per
-  tile — the BENCH_ensemble.json regression this replaces), so the
+  tile — a regression measured on the CPU in interpret mode), so the
   matrix instead lives in ONE ``(b, b, TN)`` VMEM-resident accumulator
   and each of the b pivot steps is a handful of whole-array VPU ops
   (normalize pivot row, mask it out of the factor column, one rank-1
@@ -53,7 +53,7 @@ from . import LANE, grid_block, resolve_interpret
 
 # largest block size the fully-unrolled kernels handle before register
 # pressure wins over unrolling (b^2 live lane-vectors; 64 at b=8 is
-# fine, 256 at b=16 spills — measured in BENCH_ensemble.json)
+# fine, 256 at b=16 spills — measured on the CPU in interpret mode)
 UNROLL_MAX_B = 8
 
 

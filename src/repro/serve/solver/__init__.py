@@ -7,7 +7,7 @@ Layers (one module each, composed by :class:`SolverServer`):
 * :mod:`repro.serve.solver.queue` — admission and dynamic batching:
   requests are bucketed by (problem family, shape n, method, tolerance
   class, dtype) and flushed on a max-batch-or-max-wait policy, padded
-  to benched bucket sizes, with bounded-depth backpressure
+  to fixed bucket sizes, with bounded-depth backpressure
   (:class:`RetryAfter` instead of unbounded queue growth).
 * :mod:`repro.serve.solver.trace_cache` — the shape-bucketed jit/trace
   cache keyed on (bucket shape, method, ExecPolicy fingerprint):
@@ -26,14 +26,13 @@ terminal order/step instead of the cold order-1 restart.
 from repro.core.batched import SolverSession  # re-export: the warm-start handle
 
 from .queue import (AdmissionQueue, Bundle, BucketKey, IVPRequest,
-                    RetryAfter, bucket_key, bucket_sizes_from_bench,
-                    tolerance_class)
+                    RetryAfter, bucket_key, tolerance_class)
 from .server import ProblemFamily, SolverServer
 from .trace_cache import TraceCache, TraceKey
 
 __all__ = [
     "AdmissionQueue", "Bundle", "BucketKey", "IVPRequest", "RetryAfter",
-    "bucket_key", "bucket_sizes_from_bench", "tolerance_class",
+    "bucket_key", "tolerance_class",
     "ProblemFamily", "SolverServer", "SolverSession",
     "TraceCache", "TraceKey",
 ]

@@ -17,11 +17,10 @@ is exactly that specialization set:
 Flush policy is the classic dynamic-batching pair: a bucket flushes
 when it holds ``max_batch`` requests (full bundle) or when its oldest
 request has waited ``max_wait`` seconds (latency bound).  Flushed
-groups are padded up to the nearest *bucket size* — the lane-friendly
-batch shapes the committed ``BENCH_ensemble.json`` sweep says are
-throughput sweet spots (:func:`bucket_sizes_from_bench`) — so the
-trace cache sees a tiny, fixed set of shapes no matter what sizes
-traffic arrives in.
+groups are padded up to the nearest *bucket size* — lane-friendly
+batch shapes (:data:`DEFAULT_BUCKET_SIZES` unless the server is given
+others) — so the trace cache sees a tiny, fixed set of shapes no matter
+what sizes traffic arrives in.
 
 Backpressure is bounded-depth admission: when ``max_depth`` requests
 are queued, :meth:`AdmissionQueue.offer` raises :class:`RetryAfter`
@@ -31,7 +30,6 @@ queue drains at the solver's pace.
 """
 from __future__ import annotations
 
-import json
 import math
 import time
 from dataclasses import dataclass, field
@@ -136,34 +134,6 @@ class Bundle:
 
 
 DEFAULT_BUCKET_SIZES = (64, 128, 256, 512)
-
-
-def bucket_sizes_from_bench(path: str = "BENCH_ensemble.json",
-                            max_size: int = 512,
-                            fill: Tuple[int, ...] = (64, 128, 256)
-                            ) -> Tuple[int, ...]:
-    """Derive padded bundle sizes from the committed ensemble sweep.
-
-    Every ``nsys`` the benchmark measured where the pallas kernels beat
-    the jnp oracle (ratio >= 1) is a demonstrated sweet spot; sizes
-    above ``max_size`` are dropped (a serving flush should not wait for
-    32768 requests), and the small ``fill`` sizes are merged in so
-    light traffic pads to tens of lanes, not hundreds.  Falls back to
-    :data:`DEFAULT_BUCKET_SIZES` when the file is missing — the queue
-    must admit traffic on a fresh checkout too.
-    """
-    sizes = set(fill)
-    try:
-        with open(path) as fh:
-            bench = json.load(fh)
-        for row in bench.get("results", []):
-            ratio = (row["pallas_interpret_systems_per_sec"]
-                     / row["jnp_systems_per_sec"])
-            if ratio >= 1.0 and row["nsys"] <= max_size:
-                sizes.add(int(row["nsys"]))
-    except (OSError, ValueError, KeyError):
-        return DEFAULT_BUCKET_SIZES
-    return tuple(sorted(sizes))
 
 
 @dataclass

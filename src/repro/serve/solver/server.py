@@ -43,7 +43,8 @@ from repro.core.context import Context
 from repro.core.ivp import IVP, Solution, integrate
 from repro.core.policies import XLA_FUSED
 
-from .queue import AdmissionQueue, Bundle, IVPRequest, RetryAfter
+from .queue import (DEFAULT_BUCKET_SIZES, AdmissionQueue, Bundle, IVPRequest,
+                    RetryAfter)
 from .trace_cache import TraceCache, TraceKey
 
 __all__ = ["ProblemFamily", "SolverServer", "RetryAfter",
@@ -175,7 +176,7 @@ class SolverServer:
     def __init__(self, families, ctx: Optional[Context] = None, *,
                  method: str = "ensemble_bdf", order: int = 5,
                  lin_solver=None,
-                 bucket_sizes: Optional[Tuple[int, ...]] = None,
+                 bucket_sizes: Tuple[int, ...] = DEFAULT_BUCKET_SIZES,
                  max_batch: Optional[int] = None,
                  max_wait: float = 2e-3, max_depth: int = 4096,
                  cache_size: int = 32, max_steps: int = 100_000,
@@ -195,9 +196,6 @@ class SolverServer:
         self.max_steps = max_steps
         self.clock = clock
         self.dtype = str(jnp.asarray(0.0).dtype)
-        if bucket_sizes is None:
-            from .queue import bucket_sizes_from_bench
-            bucket_sizes = bucket_sizes_from_bench()
         self.queue = AdmissionQueue(bucket_sizes=bucket_sizes,
                                     max_batch=max_batch,
                                     max_wait=max_wait,

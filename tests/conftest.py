@@ -1,9 +1,8 @@
 import jax
 
-# x64 for the numerical-analysis tests (integrators, solvers).  Model smoke
-# tests run in default precision; they opt out via their own fixtures.
+# x64 for the numerical-analysis tests (integrators, solvers).
 jax.config.update("jax_enable_x64", True)
 
 # NOTE: we deliberately do NOT set xla_force_host_platform_device_count
-# here — smoke tests and benches must see 1 device (system spec).  The
-# multi-device dry-run tests spawn subprocesses with their own XLA_FLAGS.
+# here — the tests must see 1 device.  The multi-device tests spawn
+# subprocesses with their own XLA_FLAGS.

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.apps import brusselator as br
-from repro.configs.brusselator import BrusselatorConfig
+from repro.apps.brusselator import BrusselatorConfig
 from repro.core.policies import ExecPolicy
 
 TF = 0.2

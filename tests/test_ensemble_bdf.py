@@ -100,7 +100,7 @@ def test_bdf_kinetics_jnp_vs_pallas_parity(factor_once):
     PER COMPONENT as C*(rtol*|y_i| + atol), so the comparison uses the
     same mixed form (C=100) and the ~1e-5-magnitude intermediate
     species stays genuinely exercised.  Op-level parity is gated
-    separately at 1e-10 (test_soa_carry.py, kernels_bench --smoke); the
+    separately at 1e-10 (test_soa_carry.py); the
     jnp path itself is pinned bitwise to the pre-SoA integrator in
     test_soa_carry.py."""
     nsys = 130
@@ -321,7 +321,7 @@ _MESH_SCRIPT = textwrap.dedent("""
     from repro.core.ivp import IVP, integrate
     from repro.core.linsol import BlockDiagGJ
     from repro.core.policies import ExecPolicy
-    from repro.launch.mesh import make_ensemble_mesh
+    from repro.core.batched import make_ensemble_mesh
 
     out_path, nsys, rtol, atol, tf = (sys.argv[1], int(sys.argv[2]),
                                       *map(float, sys.argv[3:6]))
@@ -486,7 +486,7 @@ def test_mesh_refusals():
     sharded."""
     from repro.core import problems
     from repro.core.ivp import IVP, integrate
-    from repro.launch.mesh import make_ensemble_mesh
+    from repro.core.batched import make_ensemble_mesh
 
     f, jac, f_soa, jac_soa = problems.robertson_family()
     p = {k: jnp.ones(4) for k in ("k1", "k2", "k3")}

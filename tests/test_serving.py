@@ -2,10 +2,8 @@
 stats hygiene, warm-start continuation, and the end-to-end server.
 
 Scaled down (tiny buckets, short horizons) so the whole file stays
-compile-bound at a few traces; the >= 10^4-request acceptance run lives
-in ``benchmarks/serving_bench.py --smoke`` (CI serving smoke step).
+compile-bound at a few traces.
 """
-import json
 
 import jax
 import jax.numpy as jnp
@@ -19,8 +17,7 @@ from repro.core.problems import (batched_robertson, batched_robertson_soa,
                                  decay_chain_family, robertson_family)
 from repro.serve.solver import (AdmissionQueue, IVPRequest, ProblemFamily,
                                 RetryAfter, SolverServer, TraceCache,
-                                TraceKey, bucket_key,
-                                bucket_sizes_from_bench, tolerance_class)
+                                TraceKey, bucket_key, tolerance_class)
 
 ROB_PARAMS = {"k1": 0.04, "k2": 1.2e4, "k3": 3e7}
 
@@ -99,20 +96,6 @@ class TestAdmissionQueue:
         q.poll(now=1.0)                            # drain
         q.offer(_req(), now=1.0)                   # admits again
         assert q.depth == 1
-
-    def test_bucket_sizes_from_bench(self, tmp_path):
-        assert bucket_sizes_from_bench(path="/nonexistent.json") == \
-            (64, 128, 256, 512)
-        p = tmp_path / "bench.json"
-        p.write_text(json.dumps({"results": [
-            {"nsys": 512, "jnp_systems_per_sec": 1.0,
-             "pallas_interpret_systems_per_sec": 2.0},     # sweet spot
-            {"nsys": 4096, "jnp_systems_per_sec": 1.0,
-             "pallas_interpret_systems_per_sec": 2.0},     # > max_size
-            {"nsys": 256, "jnp_systems_per_sec": 2.0,
-             "pallas_interpret_systems_per_sec": 1.0},     # loses
-        ]}))
-        assert bucket_sizes_from_bench(path=str(p)) == (64, 128, 256, 512)
 
 
 # ---------------------------------------------------------------------------
@@ -368,6 +351,11 @@ class TestSolverServer:
         # warm+cold rode ONE bundle: same trace, occupancy accounted
         assert np.allclose(np.asarray(warm.y), np.asarray(cold.y),
                            rtol=1e-4)
+
+    def test_default_bucket_sizes(self):
+        fr = robertson_family()
+        srv = SolverServer([ProblemFamily("robertson", 3, fr[0], fr[1])])
+        assert srv.queue.bucket_sizes == (64, 128, 256, 512)
 
     def test_backpressure_propagates(self):
         fr = robertson_family()

@@ -3,6 +3,8 @@
 * method-matrix parity: ``integrate(..., method=M)`` is
   trajectory-identical (1e-12) to each legacy entry point for every
   canonical method string;
+* every method string also runs under the Pallas and ``auto`` policies
+  with the Newton iteration count of the jnp run;
 * pluggability: swapping SPGMR <-> BlockDiagGJ on the ensemble-BDF path
   changes no trajectory beyond 1e-8 while Solution reports distinct
   solver stats and a nonzero memory high-water mark;
@@ -13,6 +15,8 @@
 * NewtonSolver tolerances sourced from ODEOptions;
 * Context counters and MemoryHelper workspace accounting.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +30,7 @@ from repro.core.ivp import IVP, METHOD_STRINGS, Solution, integrate
 from repro.core.linsol import SPGMR, BlockDiagGJ, DenseGJ
 from repro.core.memory import MemoryHelper
 from repro.core.nonlinsol import FixedPointSolver, NewtonSolver
+from repro.core.policies import AUTO, XLA_FUSED, ExecPolicy
 
 LAM = 30.0
 
@@ -111,6 +116,50 @@ def test_method_matrix_parity(method):
                                rtol=0, atol=1e-12)
     # unified stats carry the same accepted-step count
     assert int(jnp.sum(sol.stats.steps)) == int(jnp.sum(st_ref.steps))
+
+
+# small problems at loose tolerances: these cases check wiring, not accuracy
+_SMOKE_LAM = 12.0
+_SMOKE_RATES = jnp.linspace(2.0, _SMOKE_LAM, 4)
+
+
+def _smoke_problem(method):
+    lam, rates, n = _SMOKE_LAM, _SMOKE_RATES, 3
+    if method.startswith("imex"):
+        return IVP(fe=lambda t, y: lam * jnp.cos(t) * jnp.ones_like(y),
+                   fi=lambda t, y: -lam * y, y0=jnp.zeros((2,)))
+    if method.startswith("ensemble"):
+        return IVP(f=lambda t, y: -rates[:, None] * (y - jnp.cos(t)[:, None]),
+                   jac=lambda t, y: jnp.broadcast_to(
+                       -rates[:, None, None] * jnp.eye(n),
+                       (y.shape[0], n, n)),
+                   y0=jnp.zeros((rates.shape[0], n)))
+    return IVP(f=lambda t, y: -lam * (y - jnp.cos(t)), y0=jnp.zeros((2,)))
+
+
+def _smoke_solve(method, policy):
+    ctx = Context(policy=policy)
+    opts = ctx.options(rtol=1e-4, atol=1e-7, max_steps=20_000)
+    return integrate(_smoke_problem(method), 0.0, 1.0, method, ctx=ctx,
+                     opts=opts)
+
+
+@functools.lru_cache(maxsize=None)
+def _smoke_jnp_nni(method):
+    return int(_smoke_solve(method, XLA_FUSED).nni)
+
+
+@pytest.mark.parametrize("policy", [ExecPolicy(backend="pallas"), AUTO],
+                         ids=["pallas", "auto"])
+@pytest.mark.parametrize("method", METHOD_STRINGS)
+def test_method_matrix_under_kernel_policies(method, policy):
+    """integrate(method=M) under the Pallas policy (interpret mode off a
+    TPU) and under ``auto`` succeeds with a finite y and takes the same
+    Newton iterations as the jnp run of M."""
+    sol = _smoke_solve(method, policy)
+    assert bool(sol.success)
+    assert bool(jnp.all(jnp.isfinite(jnp.asarray(sol.y))))
+    assert int(sol.nni) == _smoke_jnp_nni(method)
 
 
 def test_sdirk33_is_third_order():
